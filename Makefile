@@ -15,11 +15,7 @@ verify: dev-deps test
 test:
 	$(PY) -m pytest -x -q
 
-# CI gate: the full suite except the multi-device subprocess tests.  The
-# jax.sharding/mesh API drift that broke the LM/training-layer tests on JAX
-# 0.4.37 (test_models / test_multidevice / test_train_infra /
-# test_kernels_flash::test_flash_in_model_path) is fixed by version-portable
-# guards — test_models and test_train_infra are back in the gate.
+# CI gate: the full suite except the multi-device subprocess tests.
 # test_multidevice forces 8 host devices in subprocesses, which needs real
 # cores; on throttled 2-core CI boxes it can exceed any sane wall budget, so
 # it gates separately (make test-multidevice).

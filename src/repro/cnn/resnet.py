@@ -37,7 +37,10 @@ def _bottleneck(g: XGraph, name: str, bottom: str, mid: int, out: int,
     return f"{name}/out"
 
 
-def _resnet(name: str, blocks: list[int], img: int, num_classes: int, batch: int = 1) -> XGraph:
+def _resnet(name: str, blocks: list[int], img: int, num_classes: int,
+            batch: int = 1, softmax: bool = True) -> XGraph:
+    """``softmax=False`` ends the net at the int8 fc logits: softmax is a
+    host op with no fused launch, and a server ranking classes needs none."""
     g = XGraph(name)
     last = g.input("data", (batch, img, img, 3))
     last = _conv_bn(g, "conv1", last, 64, (7, 7), stride=(2, 2))
@@ -51,13 +54,17 @@ def _resnet(name: str, blocks: list[int], img: int, num_classes: int, batch: int
                                stride=stride, project=(bi == 0))
     g.add("global_avgpool", "gap", (last,))
     g.add("fc", "fc", ("gap",), oc=num_classes)
-    g.add("softmax", "prob", ("fc",))
+    if softmax:
+        g.add("softmax", "prob", ("fc",))
     return frontend.lower(g)
 
 
-def resnet50(img: int = 224, num_classes: int = 1000, batch: int = 1) -> XGraph:
-    return _resnet("resnet50", [3, 4, 6, 3], img, num_classes, batch)
+def resnet50(img: int = 224, num_classes: int = 1000, batch: int = 1,
+             softmax: bool = True) -> XGraph:
+    return _resnet("resnet50", [3, 4, 6, 3], img, num_classes, batch, softmax)
 
 
-def resnet152(img: int = 224, num_classes: int = 1000, batch: int = 1) -> XGraph:
-    return _resnet("resnet152", [3, 8, 36, 3], img, num_classes, batch)
+def resnet152(img: int = 224, num_classes: int = 1000, batch: int = 1,
+              softmax: bool = True) -> XGraph:
+    return _resnet("resnet152", [3, 8, 36, 3], img, num_classes, batch,
+                   softmax)

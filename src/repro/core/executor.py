@@ -192,12 +192,13 @@ class Int8Executor:
     backend="ref"    : per-node jnp fixed-point ops (oracle).
     backend="pallas" : dispatches the lowered ``GroupProgram`` — one
                        ``kernels.conv_fused`` chain launch per FusedLaunch
-                       (interpret mode on CPU), the ref path per RefFallback.
+                       (compiled on an accelerator, interpreted on the CPU —
+                       ``ops.interpret_mode``), the ref path per RefFallback.
                        Bit-exact with "ref" by contract.
     """
 
     def __init__(self, g: XGraph, qm: QuantizedModel, strategy=None,
-                 backend: str = "ref", interpret: bool = True):
+                 backend: str = "ref"):
         """``strategy`` is anything with ``.groups`` / ``.horizontal`` /
         ``.meta`` — a ``pathsearch.Strategy`` or a loaded
         ``asm.CompiledArtifact`` (the plan-cache serving path).  An artifact
@@ -223,9 +224,12 @@ class Int8Executor:
             self.groups = order_groups(g, groups)
         else:
             self.groups = [[n] for n in g.compute_nodes()]
-        self.interpret = interpret
         self._fn = None
         self._fb_reasons = None
+        # devices the outputs lived on, recorded on the first call of each
+        # input shape (an executor's placement does not change after that)
+        self.devices_seen: set = set()
+        self._placed_shapes: set = set()
         self._in_shape = next((g.shape(n.name) for n in g if n.op == "input"),
                               None)
 
@@ -269,8 +273,7 @@ class Int8Executor:
                         env[node.name] = x
                 for item in items:
                     if isinstance(item, FusedLaunch):
-                        env.update(fused_ops.run_launch(
-                            item, env, qm, interpret=self.interpret))
+                        env.update(fused_ops.run_launch(item, env, qm))
                     else:
                         for name in item.nodes:
                             env[name] = _int8_node(g, g.nodes[name], env, qm)
@@ -295,6 +298,10 @@ class Int8Executor:
         if self._fn is None:
             self._fn = self._build()
         out = self._fn(jnp.asarray(x))
+        if x.shape not in self._placed_shapes:
+            self._placed_shapes.add(x.shape)
+            for v in out.values():
+                self.devices_seen.update(v.devices())
         REGISTRY.counter("executor.calls").inc()
         if self.program is not None:
             # the jitted program dispatches every item per call; meta carries

@@ -131,16 +131,17 @@ ZU9 = DeviceModel(
 # The MXU is a 128x128 systolic array: ic_p=oc_p=128 (contraction/output
 # lanes), h_p=8 (sublanes).  Effective compute rate comes from the published
 # 197 TFLOP/s bf16 peak via peak_ops_override; (ic_p, oc_p, h_p) still drive
-# tile alignment and ragged-tile rounding.
-_V5E_VMEM = 96 * 1024 * 1024
+# tile alignment and ragged-tile rounding.  The VMEM figure is also the
+# scoped-VMEM limit every fused launch is compiled with (``conv_fused``).
+V5E_VMEM_BYTES = 96 * 1024 * 1024
 
 TPU_V5E = DeviceModel(
     name="tpu_v5e",
     freq_hz=940e6,
     ic_p=128, oc_p=128, h_p=8,
-    buf_in_bytes=int(_V5E_VMEM * 0.45),
-    buf_weights_bytes=int(_V5E_VMEM * 0.35),
-    buf_out_bytes=int(_V5E_VMEM * 0.20),
+    buf_in_bytes=int(V5E_VMEM_BYTES * 0.45),
+    buf_weights_bytes=int(V5E_VMEM_BYTES * 0.35),
+    buf_out_bytes=int(V5E_VMEM_BYTES * 0.20),
     dram_bw_bytes_per_s=819e9,
     elem_bytes=1,                          # int8 inference data path
     ici_bw_bytes_per_s=50e9,

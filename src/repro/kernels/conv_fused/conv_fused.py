@@ -30,8 +30,8 @@ of zero-padded conv, -128-padded (and ceil-extended) maxpool, and zero-padded
 Tile shape: the grid is (batch, row tiles, width tiles, OC tiles) — all
 three tile extents are compile-time decisions (``FusedLaunch.tile``, chosen
 by the tile-shape search; kernel heuristics otherwise).  Width tiles read
-halo-overlapped input slices (``jax.lax.dynamic_slice`` off the staged
-image, mirroring the row axis), and ragged bottom/right tiles compute into
+halo-overlapped windows of the staged image (ref loads at 8-aligned offsets,
+mirroring the row axis), and ragged bottom/right tiles compute into
 padded-output slack that the launcher slices off — the same padded-
 coordinate masking that handles ceil-mode pools keeps every valid position
 bit-exact at interior tile boundaries.  The OC axis tiles the FINAL conv's
@@ -50,6 +50,9 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from repro.hw.device import V5E_VMEM_BYTES
 
 I8_MIN = -128
 
@@ -140,52 +143,91 @@ def chain_geometry(chain, th: int, oh: int, ow: int, tw: int | None = None
 
 
 # ------------------------------------------------------------------- kernels
-def _conv_apply(t, w_ref, b_ref, st, out_r, out_c):
-    _, _, kh, kw, sh, sw, _, _, dh, dw, shift, relu = st[:12]
-    in_r, in_c, ic = t.shape
-    acc = jnp.zeros((out_r * out_c, w_ref.shape[-1]), jnp.int32)
+def _ds(start, size: int, stride: int = 1):
+    return pl.ds(start, size, stride) if stride > 1 else pl.ds(start, size)
+
+
+def _col_start(jw, f: int, n_w: int):
+    """Width offset of width tile ``jw``: static 0 for a single column, else
+    ``jw * f`` (``f`` is a multiple of 8 once ``ops`` legalized the tile,
+    which keeps the dynamic sublane offset aligned for Mosaic)."""
+    if n_w == 1:
+        return 0
+    start = jw * f
+    return pl.multiple_of(start, 8) if f % 8 == 0 else start
+
+
+def _rows(n: int, body) -> None:
+    """``body(r)`` for every output row r of a stage.  A loop, not an unroll:
+    each iteration touches one (width, channels) row, which keeps the Mosaic
+    program small and every value 2-D (no sublane-merging reshapes)."""
+    def step(r, carry):
+        body(r)
+        return carry
+    jax.lax.fori_loop(0, n, step, 0)
+
+
+LANES = 128
+
+
+def _groups(c: int) -> list:
+    """(offset, width) of the 128-lane channel groups of a C-channel map.
+    Stage buffers hold one group per leading index: Mosaic's strided load
+    needs a minor dimension of at most 128 lanes."""
+    return [(g, min(LANES, c - g)) for g in range(0, c, LANES)]
+
+
+def _buf_shape(rows: int, cols: int, c: int) -> tuple:
+    return (len(_groups(c)), rows, cols, min(c, LANES))
+
+
+def _col_valid(shape, col0, q, true_w):
+    cols = jax.lax.broadcasted_iota(jnp.int32, shape, 0) + col0
+    return (cols >= q) & (cols < q + true_w)
+
+
+def _conv_acc(src, c_in, w_ref, r, out_c, kh, kw, sh, sw, dh, dw):
+    """int32 accumulator of output row r of a conv read from the int32 VMEM
+    buffer ``src``: kh*kw (strided) window loads per channel group, each a
+    matmul against that group's weight rows.  The buffer holds saturated int8
+    values, so int8 operands with int32 accumulation are exact (Mosaic has no
+    int32 matmul)."""
+    acc = jnp.zeros((out_c, w_ref.shape[-1]), jnp.int32)
     for i in range(kh):
         for j in range(kw):
-            sl = jax.lax.slice(
-                t, (i * dh, j * dw, 0),
-                (i * dh + (out_r - 1) * sh + 1,
-                 j * dw + (out_c - 1) * sw + 1, ic),
-                (sh, sw, 1))
-            acc = acc + jnp.dot(sl.reshape(out_r * out_c, ic),
-                                w_ref[i, j].astype(jnp.int32),
-                                preferred_element_type=jnp.int32)
-    acc = acc + b_ref[...].astype(jnp.int32)[None, :]
-    y = _round_shift(acc, shift)
+            for g, (lo, cw) in enumerate(_groups(c_in)):
+                patch = src[g, r * sh + i * dh, _ds(j * dw, out_c, sw), :cw]
+                acc = acc + jnp.dot(patch.astype(jnp.int8),
+                                    w_ref[i, j, lo:lo + cw],
+                                    preferred_element_type=jnp.int32)
+    return acc
+
+
+def _conv_row(src, c_in, w_ref, b_ref, st, r, out_c):
+    _, _, kh, kw, sh, sw, _, _, dh, dw, shift, relu = st[:12]
+    acc = _conv_acc(src, c_in, w_ref, r, out_c, kh, kw, sh, sw, dh, dw)
+    y = _round_shift(acc + b_ref[...], shift)
     if relu:
         y = jnp.maximum(y, 0)
-    return jnp.clip(y, -128, 127).reshape(out_r, out_c, -1)
+    return jnp.clip(y, -128, 127)
 
 
-def _pool_apply(t, st, out_r, out_c):
+def _pool_row(src, g, cw, st, r, out_c):
     _, _, pkind, kph, kpw, sph, spw = st[:7]
-    cnt = st[11]
-    c = t.shape[-1]
-    if pkind == "max":
-        best = None
-        for i in range(kph):
-            for j in range(kpw):
-                win = jax.lax.slice(
-                    t, (i, j, 0),
-                    (i + (out_r - 1) * sph + 1, j + (out_c - 1) * spw + 1, c),
-                    (sph, spw, 1))
-                best = win if best is None else jnp.maximum(best, win)
-        return best
-    if pkind == "gap":
-        s = jnp.sum(t, axis=(0, 1), keepdims=True)
-    else:
-        s = None
-        for i in range(kph):
-            for j in range(kpw):
-                win = jax.lax.slice(
-                    t, (i, j, 0),
-                    (i + (out_r - 1) * sph + 1, j + (out_c - 1) * spw + 1, c),
-                    (sph, spw, 1))
-                s = win if s is None else s + win
+    s = None
+    for i in range(kph):
+        for j in range(kpw):
+            win = src[g, r * sph + i, _ds(j, out_c, spw), :cw]
+            if s is None:
+                s = win
+            elif pkind == "max":
+                s = jnp.maximum(s, win)
+            else:
+                s = s + win
+    return s if pkind == "max" else _avg(s, st[11])
+
+
+def _avg(s, cnt: int):
     return jnp.sign(s) * ((jnp.abs(s) + cnt // 2) // cnt)
 
 
@@ -197,60 +239,109 @@ def _elt_apply(t, side, st):
     return jnp.clip(z, -128, 127)
 
 
-def _mask(t, row0, col0, q, true_h, true_w, fill):
-    out_r, out_c, _ = t.shape
-    rows = jax.lax.broadcasted_iota(jnp.int32, (out_r, out_c, 1), 0) + row0
-    cols = jax.lax.broadcasted_iota(jnp.int32, (out_r, out_c, 1), 1) + col0
-    valid = ((rows >= q[0]) & (rows < q[0] + true_h)
-             & (cols >= q[1]) & (cols < q[1] + true_w))
-    return jnp.where(valid, t, fill)
+def _true_hw(st) -> tuple:
+    return ((st[12], st[13]) if st[0] == "conv" else
+            (st[9], st[10]) if st[0] == "pool" else (st[5], st[6]))
 
 
-def _chain_kernel(*refs, chain, geom):
+def _chain_kernel(*refs, chain, geom, chans):
+    """Every stage reads its input rows from an int32 VMEM buffer (strided
+    windows are ref loads: Mosaic has no strided value slice and no strided
+    int8 load) and writes its masked output rows into the next stage's
+    buffer; the last stage writes the output block.  ``chans[i]`` is the
+    channel count of stage i's input buffer."""
     n_conv = sum(1 for st in chain if st[0] == "conv")
     n_side = sum(1 for st in chain if st[0] == "elt")
     x_ref = refs[0]
     wrefs = refs[1:1 + 2 * n_conv]
     srefs = refs[1 + 2 * n_conv:1 + 2 * n_conv + n_side]
-    o_ref = refs[-1]
+    o_ref = refs[1 + 2 * n_conv + n_side]
+    bufs = refs[2 + 2 * n_conv + n_side:]
     j = pl.program_id(1)
     jw = pl.program_id(2)
+    n_w = geom["n_w"]
+    row_in = j * geom["f_in"]
+    col_in = pl.ds(_col_start(jw, geom["fw_in"], n_w), geom["in_cols"])
 
-    t = x_ref[0, pl.dslice(j * geom["f_in"], geom["in_rows"])]
-    t = jax.lax.dynamic_slice_in_dim(
-        t, jw * geom["fw_in"], geom["in_cols"], axis=1).astype(jnp.int32)
+    def stage_in(r):
+        for g, (lo, cw) in enumerate(_groups(chans[0])):
+            bufs[0][g, r, :, :cw] = x_ref[0, row_in + r, col_in,
+                                          lo:lo + cw].astype(jnp.int32)
+    _rows(geom["in_rows"], stage_in)
+
     wi = si = 0
     for i, st in enumerate(chain):
         out_r, out_c = geom["rows"][i], geom["cols"][i]
+        last = i + 1 == len(chain)
+        src, c_in = bufs[i], chans[i]
+        if st[0] == "pool" and st[2] == "gap":
+            # one output position: the whole staged map, summed row by row
+            for g, (lo, cw) in enumerate(_groups(c_in)):
+                tot = jnp.zeros((1, cw), jnp.int32)
+                for r in range(src.shape[1]):
+                    tot = tot + jnp.sum(src[g, r, :, :cw], axis=0,
+                                        keepdims=True)
+                o_ref[0, 0, :, lo:lo + cw] = _sat8(_avg(tot, st[11]))
+            continue
         if st[0] == "conv":
-            t = _conv_apply(t, wrefs[2 * wi], wrefs[2 * wi + 1], st,
-                            out_r, out_c)
+            w_ref, b_ref = wrefs[2 * wi], wrefs[2 * wi + 1]
             wi += 1
-        elif st[0] == "pool":
-            t = _pool_apply(t, st, out_r, out_c)
-        else:
-            side = srefs[si][0, pl.dslice(j * geom["fout"][i], out_r)]
-            side = jax.lax.dynamic_slice_in_dim(
-                side, jw * geom["foutw"][i], out_c, axis=1).astype(jnp.int32)
-            t = _elt_apply(t, side, st)
+        if st[0] == "elt":
+            s_ref, s_row = srefs[si], j * geom["fout"][i]
+            s_col = pl.ds(_col_start(jw, geom["foutw"][i], n_w), out_c)
             si += 1
-        if i + 1 < len(chain):
-            true_h, true_w = (st[12], st[13]) if st[0] == "conv" else \
-                             (st[9], st[10]) if st[0] == "pool" else \
-                             (st[5], st[6])
-            t = _mask(t, j * geom["fout"][i], jw * geom["foutw"][i],
-                      geom["q"][i], true_h, true_w, _fill_of(chain[i + 1]))
-    o_ref[0] = _sat8(t)
+        if not last:
+            true_h, true_w = _true_hw(st)
+            row0 = j * geom["fout"][i]
+            q = geom["q"][i]
+            fill = _fill_of(chain[i + 1])
+
+        def emit(r, g, lo, cw, y):
+            if last:
+                o_ref[0, r, :, lo:lo + cw] = _sat8(y)
+                return
+            ok = _col_valid(y.shape, jw * geom["foutw"][i], q[1], true_w)
+            row = row0 + r
+            ok = ok & (row >= q[0]) & (row < q[0] + true_h)
+            bufs[i + 1][g, r, :, :cw] = jnp.where(ok, y, fill)
+
+        def body(r):
+            if st[0] == "conv":
+                y = _conv_row(src, c_in, w_ref, b_ref, st, r, out_c)
+                for g, (lo, cw) in enumerate(_groups(y.shape[-1])):
+                    emit(r, g, lo, cw, y[:, lo:lo + cw])
+                return
+            for g, (lo, cw) in enumerate(_groups(c_in)):
+                if st[0] == "pool":
+                    y = _pool_row(src, g, cw, st, r, out_c)
+                else:
+                    side = s_ref[0, s_row + r, s_col, lo:lo + cw]
+                    y = _elt_apply(src[g, r, :, :cw],
+                                   side.astype(jnp.int32), st)
+                emit(r, g, lo, cw, y)
+        _rows(out_r, body)
+
+
+def _compiler_params(interpret: bool):
+    """Scoped-VMEM limit of one launch: the budget ``hw.TPU_V5E`` plans tiles
+    under (Mosaic's 16 MiB default is too small for the stem's whole-image
+    block, ~15 MiB lane-padded and double-buffered)."""
+    return (None if interpret
+            else pltpu.CompilerParams(vmem_limit_bytes=V5E_VMEM_BYTES))
+
+
+def _vmem(shape):
+    return pltpu.VMEM(tuple(int(d) for d in shape), jnp.int32)
 
 
 def fused_chain_pallas(x_pad, weights, biases, sides, *, chain, th, toc,
-                       oh, ow, oc, tw=None, interpret=True):
+                       oh, ow, oc, tw=None, interpret=False):
     """Launch a lowered chain as one kernel.
 
     x_pad:   (N, Hp, Wp, C) int8, pre-padded per ``chain_geometry`` with the
              first stage's pad identity.
     weights: one (KH, KW, IC, OC) int8 panel per conv stage, in chain order.
-    biases:  one (OC,) int32 per conv stage.
+    biases:  one (1, OC) int32 row per conv stage.
     sides:   one pre-padded (N, sHp, sWp, OCs) int8 per elt stage.
     chain:   static stage specs (see ``core.lower``).
     tw:      width-tile size (default: full width).  Ragged bottom/right
@@ -272,11 +363,12 @@ def fused_chain_pallas(x_pad, weights, biases, sides, *, chain, th, toc,
         if ci == last_conv:
             in_specs.append(pl.BlockSpec((kh, kw, ic, toc),
                                          lambda i, j, jw, k: (0, 0, 0, k)))
-            in_specs.append(pl.BlockSpec((toc,), lambda i, j, jw, k: (k,)))
+            in_specs.append(pl.BlockSpec((1, toc), lambda i, j, jw, k: (0, k)))
         else:
             in_specs.append(pl.BlockSpec((kh, kw, ic, oc_i),
                                          lambda i, j, jw, k: (0, 0, 0, 0)))
-            in_specs.append(pl.BlockSpec((oc_i,), lambda i, j, jw, k: (0,)))
+            in_specs.append(pl.BlockSpec((1, oc_i),
+                                         lambda i, j, jw, k: (0, 0)))
         args.extend([w, b])
     elt_idx = [i for i, st in enumerate(chain) if st[0] == "elt"]
     for ei, s in zip(elt_idx, sides):
@@ -289,7 +381,22 @@ def fused_chain_pallas(x_pad, weights, biases, sides, *, chain, th, toc,
                                          lambda i, j, jw, k: (i, 0, 0, 0)))
         args.append(s)
 
-    kern = functools.partial(_chain_kernel, chain=chain, geom=geom)
+    # one int32 buffer per stage input: the staged image window, then every
+    # intermediate map (channels: a conv's panel width — TOC for the final
+    # conv — else the channels it was fed)
+    chans, wi = [c], 0
+    for i, st in enumerate(chain[:-1]):
+        ch = chans[-1]
+        if st[0] == "conv":
+            ch = toc if i == last_conv else int(weights[wi].shape[-1])
+            wi += 1
+        chans.append(ch)
+    extents = [(geom["in_rows"], geom["in_cols"])] + [
+        (geom["rows"][i], geom["cols"][i]) for i in range(len(chain) - 1)]
+    bufs = [_vmem(_buf_shape(r, cc, ch)) for (r, cc), ch in zip(extents, chans)]
+
+    kern = functools.partial(_chain_kernel, chain=chain, geom=geom,
+                             chans=tuple(chans))
     fn = pl.pallas_call(
         kern,
         grid=grid,
@@ -298,46 +405,44 @@ def fused_chain_pallas(x_pad, weights, biases, sides, *, chain, th, toc,
                                lambda i, j, jw, k: (i, j, jw, k)),
         out_shape=jax.ShapeDtypeStruct(
             (n, geom["n_h"] * th, geom["n_w"] * tw, oc), jnp.int8),
+        scratch_shapes=bufs,
+        compiler_params=_compiler_params(interpret),
         interpret=interpret,
     )
     return fn(*args)[:, :oh, :ow]
 
 
 # ------------------------------------------------------ horizontal (stacked)
-def _horizontal_kernel(x_ref, w_ref, b_ref, s_ref, r_ref, o_ref, *,
-                       kh, kw, sh, sw, th, tw):
+def _horizontal_kernel(x_ref, w_ref, b_ref, s_ref, r_ref, o_ref, buf, *,
+                       kh, kw, sh, sw, th, tw, n_w, ic):
     j = pl.program_id(1)
     jw = pl.program_id(2)
-    in_rows = (th - 1) * sh + kh
-    in_cols = (tw - 1) * sw + kw
-    t = x_ref[0, pl.dslice(j * th * sh, in_rows)]
-    t = jax.lax.dynamic_slice_in_dim(
-        t, jw * tw * sw, in_cols, axis=1).astype(jnp.int32)
-    ic = t.shape[-1]
-    toc = w_ref.shape[-1]
-    acc = jnp.zeros((th * tw, toc), jnp.int32)
-    for i in range(kh):
-        for jj in range(kw):
-            sl = jax.lax.slice(t, (i, jj, 0),
-                               (i + (th - 1) * sh + 1,
-                                jj + (tw - 1) * sw + 1, ic), (sh, sw, 1))
-            acc = acc + jnp.dot(sl.reshape(th * tw, ic),
-                                w_ref[i, jj].astype(jnp.int32),
-                                preferred_element_type=jnp.int32)
-    acc = acc + b_ref[...].astype(jnp.int32)[None, :]
-    y = _round_shift_vec(acc.reshape(th, tw, toc), s_ref[...])
-    y = jnp.where(r_ref[...].reshape(1, 1, toc) != 0, jnp.maximum(y, 0), y)
-    o_ref[0] = _sat8(y)
+    in_rows, in_cols = buf.shape[1:3]
+    row_in = j * th * sh
+    col_in = pl.ds(_col_start(jw, tw * sw, n_w), in_cols)
+
+    def stage_in(r):
+        for g, (lo, cw) in enumerate(_groups(ic)):
+            buf[g, r, :, :cw] = x_ref[0, row_in + r, col_in,
+                                      lo:lo + cw].astype(jnp.int32)
+    _rows(in_rows, stage_in)
+
+    def body(r):
+        acc = _conv_acc(buf, ic, w_ref, r, tw, kh, kw, sh, sw, 1, 1)
+        y = _round_shift_vec(acc + b_ref[...], s_ref[...])
+        y = jnp.where(r_ref[...] != 0, jnp.maximum(y, 0), y)
+        o_ref[0, r] = _sat8(y)
+    _rows(th, body)
 
 
 def fused_horizontal_pallas(x_pad, w, b, shift_vec, relu_vec, *, stride,
-                            th, toc, oh, ow, tw=None, interpret=True):
+                            th, toc, oh, ow, tw=None, interpret=False):
     """Sibling convs batched over OC-stacked weights.
 
-    w: (KH, KW, IC, sum_OC) int8 stacked along OC; shift_vec/relu_vec: int32
-    per-channel requantization shift / ReLU mask.  x_pad pre-padded (the
-    launcher pads enough physical slack for the ragged bottom/right tiles;
-    their zero-fed slack positions are sliced off here).
+    w: (KH, KW, IC, sum_OC) int8 stacked along OC; b, shift_vec, relu_vec:
+    (1, sum_OC) int32 bias, per-channel requantization shift and ReLU mask.
+    x_pad pre-padded (the launcher pads enough physical slack for the ragged
+    bottom/right tiles; their zero-fed slack positions are sliced off here).
     """
     n, hp, wp, ic = x_pad.shape
     kh, kw, _, oc = w.shape
@@ -346,20 +451,22 @@ def fused_horizontal_pallas(x_pad, w, b, shift_vec, relu_vec, *, stride,
     n_h = -(-oh // th)
     n_w = -(-ow // tw)
     kern = functools.partial(_horizontal_kernel, kh=kh, kw=kw, sh=sh, sw=sw,
-                             th=th, tw=tw)
+                             th=th, tw=tw, n_w=n_w, ic=ic)
+    vec = pl.BlockSpec((1, toc), lambda i, j, jw, k: (0, k))
     fn = pl.pallas_call(
         kern,
         grid=(n, n_h, n_w, oc // toc),
         in_specs=[
             pl.BlockSpec((1, hp, wp, ic), lambda i, j, jw, k: (i, 0, 0, 0)),
             pl.BlockSpec((kh, kw, ic, toc), lambda i, j, jw, k: (0, 0, 0, k)),
-            pl.BlockSpec((toc,), lambda i, j, jw, k: (k,)),
-            pl.BlockSpec((toc,), lambda i, j, jw, k: (k,)),
-            pl.BlockSpec((toc,), lambda i, j, jw, k: (k,)),
+            vec, vec, vec,
         ],
         out_specs=pl.BlockSpec((1, th, tw, toc),
                                lambda i, j, jw, k: (i, j, jw, k)),
         out_shape=jax.ShapeDtypeStruct((n, n_h * th, n_w * tw, oc), jnp.int8),
+        scratch_shapes=[_vmem(_buf_shape((th - 1) * sh + kh,
+                                         (tw - 1) * sw + kw, ic))],
+        compiler_params=_compiler_params(interpret),
         interpret=interpret,
     )
     return fn(x_pad, w, b, shift_vec, relu_vec)[:, :oh, :ow]

@@ -11,6 +11,7 @@
 """
 from __future__ import annotations
 
+import warnings
 from functools import partial
 
 import jax
@@ -28,33 +29,56 @@ def _tile_rows(oh: int, pref=(8, 4, 2, 1)) -> int:
     return 1
 
 
-def _tile_oc(oc: int) -> int:
-    for t in (128, 64, 32, 16, 8, 4, 2, 1):
-        if oc % t == 0 and t <= oc:
-            return t
-    return oc
+def _tile_oc(oc: int, n_conv: int = 1) -> int:
+    """T_oc heuristic: 128 (one MXU panel) where it divides OC, else the full
+    OC — the only extents the (8, 128) block rule admits on the lane axis.
+    A chain with convs upstream of its final conv runs the full OC: those
+    stages are recomputed for every OC tile."""
+    return 128 if (n_conv == 1 and oc % 128 == 0) else oc
 
 
-def _resolve_tile(tile, oh: int, ow: int, oc: int, has_conv: bool) -> tuple:
+def _legal_tw(tw: int, ow: int) -> int:
+    """A width tile is the full width or a multiple of 8 (sublane block rule;
+    it also keeps the kernel's dynamic width offsets 8-aligned)."""
+    return ow if tw >= ow else min(ow, -(-tw // 8) * 8)
+
+
+def _legal_toc(toc: int, oc: int) -> bool:
+    """An OC tile the chip admits: a 128-multiple divisor of OC, or OC."""
+    return toc == oc or (oc % toc == 0 and toc % 128 == 0)
+
+
+def _resolve_tile(tile, oh: int, ow: int, oc: int, n_conv: int) -> tuple:
     """(th, tw, toc) the launch executes.
 
     A serialized tile shape (``FusedLaunch.tile``, chosen by the tile-shape
-    search) wins, clamped to the output extents; a T_oc that does not divide
-    OC falls back to the divisor heuristic (the kernel's OC grid axis cannot
-    run ragged — weights would need padding).  Without a shape the PR-4
-    heuristics apply: full width, row tiles from the largest divisor, T_oc
-    from the power-of-two divisor ladder.
+    search) wins, clamped to the output extents and legalized for the chip's
+    block rule: T_w rounds up to a multiple of 8 (or the full width) and a
+    T_oc that is neither a 128-multiple divisor of OC nor OC itself is
+    replaced by the heuristic, with a warning — the OC grid axis cannot run
+    ragged and Mosaic refuses misaligned lane blocks.  Without a shape: row
+    tiles from the largest divisor, full width, heuristic T_oc.
     """
-    if tile:
-        th = max(1, min(int(tile[0]), oh))
-        tw = max(1, min(int(tile[1]), ow))
-        toc = max(1, min(int(tile[2]), oc))
-        if not has_conv:
-            toc = oc
-        elif oc % toc:
-            toc = _tile_oc(oc)
-        return th, tw, toc
-    return _tile_rows(oh), ow, (_tile_oc(oc) if has_conv else oc)
+    if not tile:
+        return (_tile_rows(oh), ow,
+                _tile_oc(oc, n_conv) if n_conv else oc)
+    th = max(1, min(int(tile[0]), oh))
+    tw = _legal_tw(max(1, int(tile[1])), ow)
+    toc = max(1, min(int(tile[2]), oc))
+    if not n_conv:
+        toc = oc
+    elif not _legal_toc(toc, oc):
+        legal = _tile_oc(oc, n_conv)
+        warnings.warn(f"tile T_oc={toc} is not a legal lane block for "
+                      f"OC={oc}; running T_oc={legal}", stacklevel=2)
+        toc = legal
+    return th, tw, toc
+
+
+def interpret_mode() -> bool:
+    """Kernels run under the Pallas interpreter exactly when the default
+    backend is the CPU; on an accelerator every launch is compiled."""
+    return jax.default_backend() == "cpu"
 
 
 def supports(*, depthwise=False, **_ignored) -> bool:
@@ -78,13 +102,14 @@ def _pad_to(x, top: int, left: int, h_req: int, w_req: int, fill: int):
                                    "tile"))
 def _run_chain(x, weights, biases, sides, *, chain, oh, ow, oc, interpret,
                tile=()):
-    has_conv = any(st[0] == "conv" for st in chain)
-    th, tw, toc = _resolve_tile(tile, oh, ow, oc, has_conv)
+    n_conv = sum(1 for st in chain if st[0] == "conv")
+    th, tw, toc = _resolve_tile(tile, oh, ow, oc, n_conv)
     geom = chain_geometry(chain, th, oh, ow, tw)
     xp = _pad_to(x, geom["q_in"][0], geom["q_in"][1],
                  geom["h_req"], geom["w_req"], geom["fill0"])
     sp = tuple(_pad_to(s, sg["q"][0], sg["q"][1], sg["h_req"], sg["w_req"], 0)
                for s, sg in zip(sides, geom["sides"]))
+    biases = tuple(b.reshape(1, -1) for b in biases)
     return fused_chain_pallas(xp, weights, biases, sp, chain=chain, th=th,
                               tw=tw, toc=toc, oh=oh, ow=ow, oc=oc,
                               interpret=interpret)
@@ -96,19 +121,22 @@ def _run_horizontal(x, w, b, shift_vec, relu_vec, *, stride, pad, oh, ow,
                     interpret, tile=()):
     kh, kw = w.shape[:2]
     sh, sw = stride
-    th, tw, toc = _resolve_tile(tile, oh, ow, int(w.shape[-1]), True)
+    th, tw, toc = _resolve_tile(tile, oh, ow, int(w.shape[-1]), 1)
     n_h = -(-oh // th)
     n_w = -(-ow // tw)
     xp = _pad_to(x, pad[0], pad[1], (n_h * th - 1) * sh + kh,
                  (n_w * tw - 1) * sw + kw, 0)
+    b, shift_vec, relu_vec = (v.reshape(1, -1)
+                              for v in (b, shift_vec, relu_vec))
     return fused_horizontal_pallas(xp, w, b, shift_vec, relu_vec,
                                    stride=stride, th=th, tw=tw, toc=toc,
                                    oh=oh, ow=ow, interpret=interpret)
 
 
 # ------------------------------------------------------------ executor hook
-def run_launch(launch, env: dict, qm, interpret: bool = True) -> dict:
+def run_launch(launch, env: dict, qm) -> dict:
     """Execute one FusedLaunch; returns {tensor name: int8 array}."""
+    interpret = interpret_mode()
     if launch.kind == "horizontal":
         x = env[launch.in_name]
         w = jnp.concatenate(
@@ -153,7 +181,7 @@ def run_launch(launch, env: dict, qm, interpret: bool = True) -> dict:
 
 # ------------------------------------------------------------ legacy wrapper
 def fused_conv_block(x, w, b, *, stride=(1, 1), pad=(0, 0), shift=0,
-                     relu=False, pool=None, eltwise=None, interpret=True):
+                     relu=False, pool=None, eltwise=None):
     """Single conv (+maxpool | +eltwise) as a 1-2 stage chain.
 
     eltwise = (side, s_conv, s_side, relu_out) or None; pool = (kp, sp) with
@@ -179,4 +207,4 @@ def fused_conv_block(x, w, b, *, stride=(1, 1), pad=(0, 0), shift=0,
                        bool(relu_out), oh, ow))
         sides = (side,)
     return _run_chain(x, (w,), (b,), sides, chain=tuple(stages), oh=oh,
-                      ow=ow, oc=oc, interpret=interpret)
+                      ow=ow, oc=oc, interpret=interpret_mode())

@@ -9,33 +9,22 @@ from __future__ import annotations
 import jax
 
 
-def _axis_type_kwargs(n_axes: int) -> dict:
-    """``axis_types=`` kwarg when this JAX has it (>= 0.5), else nothing —
-    0.4.x meshes are implicitly Auto, which is what we ask for anyway."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return {}
-    return {"axis_types": (axis_type.Auto,) * n_axes}
-
-
 def make_production_mesh(*, multi_pod: bool = False):
     """16x16 chips per pod ("data" x "model"); two pods add a "pod" axis."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **_axis_type_kwargs(len(axes)))
+    return make_mesh(shape, axes)
 
 
 def make_mesh(shape, axes):
     """Arbitrary mesh (tests use small ones, e.g. (2, 4))."""
     return jax.make_mesh(tuple(shape), tuple(axes),
-                         **_axis_type_kwargs(len(axes)))
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def mesh_context(mesh):
-    """Portable ``with <mesh active>`` context: ``jax.set_mesh`` on newer
-    JAX, the ``Mesh`` object's own context manager on 0.4.x."""
-    set_mesh = getattr(jax, "set_mesh", None)
-    return set_mesh(mesh) if set_mesh is not None else mesh
+    """``with <mesh active>`` context."""
+    return jax.set_mesh(mesh)
 
 
 def data_axes(mesh) -> tuple[str, ...]:

@@ -36,10 +36,7 @@ def make_train_step(cfg: ArchConfig, opt_cfg: AdamWConfig = AdamWConfig(),
     "late" — the microbatch loop runs inside shard_map over the data axes
              (model axis stays auto/GSPMD): grads accumulate locally and are
              psum'd ONCE per step — grad-sync collective bytes / grad_accum.
-             Requires ``mesh`` and a JAX with native ``jax.shard_map``
-             (partial-auto shard_map crashes XLA on 0.4.x meshes with a
-             model axis — there "late" degrades to the numerically identical
-             per-microbatch path).
+             Requires ``mesh``.
     """
     gdt = jnp.dtype(grad_dtype)
 
@@ -76,12 +73,8 @@ def make_train_step(cfg: ArchConfig, opt_cfg: AdamWConfig = AdamWConfig(),
         from repro.launch.mesh import data_axes
 
         dp = data_axes(mesh)
-        if (not hasattr(jax, "shard_map")
-                and set(mesh.axis_names) - set(dp)):
-            grad_sync = "auto"   # see docstring: 0.4.x partial-auto crash
-        # each microbatch must still split across the data axes
-        if grad_accum > 1 and dp:
-            pass  # divisibility asserted by shard_map at trace time
+        # each microbatch must still split across the data axes: shard_map
+        # asserts the divisibility at trace time
 
         def grad_fn(params, micro_local):
             g, l = accum_grads(params, micro_local)
@@ -96,15 +89,9 @@ def make_train_step(cfg: ArchConfig, opt_cfg: AdamWConfig = AdamWConfig(),
             in_specs = (jax.tree.map(lambda _: P(), params),
                         jax.tree.map(lambda x: P(None, dp), micro))
             out_specs = (jax.tree.map(lambda _: P(), params), P())
-            if hasattr(jax, "shard_map"):
-                fn = jax.shard_map(grad_fn, mesh=mesh, in_specs=in_specs,
-                                   out_specs=out_specs, axis_names=set(dp),
-                                   check_vma=False)
-            else:  # JAX 0.4.x: non-mapped mesh axes go through ``auto``
-                from jax.experimental.shard_map import shard_map
-                fn = shard_map(grad_fn, mesh=mesh, in_specs=in_specs,
-                               out_specs=out_specs, check_rep=False,
-                               auto=frozenset(mesh.axis_names) - set(dp))
+            fn = jax.shard_map(grad_fn, mesh=mesh, in_specs=in_specs,
+                               out_specs=out_specs, axis_names=set(dp),
+                               check_vma=False)
             return fn(params, micro)
 
     def train_step(state, batch):

@@ -7,25 +7,11 @@ from jax.sharding import PartitionSpec as P
 
 
 def _mesh_dims():
-    """{axis name: size} of the mesh in effect, or None.
-
-    Version-portable: newer JAX exposes ``jax.sharding.get_abstract_mesh``;
-    on 0.4.x the ``with mesh:`` context manager sets the thread-local
-    physical mesh reachable through ``jax.interpreters.pxla`` (public
-    re-export, no ``jax._src`` reach-in)."""
-    get_abstract = getattr(jax.sharding, "get_abstract_mesh", None)
-    if get_abstract is not None:
-        mesh = get_abstract()
-        if mesh is None or not mesh.axis_names:
-            return None
-        return dict(zip(mesh.axis_names, mesh.axis_sizes))
-    from jax.interpreters import pxla
-
-    env = getattr(getattr(pxla, "thread_resources", None), "env", None)
-    mesh = getattr(env, "physical_mesh", None)
-    if mesh is None or mesh.empty or not mesh.axis_names:
+    """{axis name: size} of the mesh in effect, or None."""
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh is None or not mesh.axis_names:
         return None
-    return dict(mesh.shape)
+    return dict(zip(mesh.axis_names, mesh.axis_sizes))
 
 
 def constrain(x, *logical):

@@ -117,7 +117,7 @@ class DriftProfiler:
 
     def __init__(self, g, qm, artifact, dev, profile, *, every: int = 64,
                  warmup: int = 1, repeats: int = 3, band: float | None = None,
-                 measure_fn=None, interpret: bool = True,
+                 measure_fn=None,
                  window: int = 8, registry=None, labels: dict | None = None):
         if every < 1:
             raise ValueError("every must be >= 1")
@@ -129,7 +129,6 @@ class DriftProfiler:
         self.every = every
         self.warmup, self.repeats = warmup, repeats
         self.measure_fn = measure_fn
-        self.interpret = interpret
         self.window = window
         self.registry = registry if registry is not None else obs_metrics.REGISTRY
         # ``labels`` tags every emitted gauge (multi-tenant serving labels
@@ -191,8 +190,7 @@ class DriftProfiler:
             key = _unit_key(item)
             if self.measure_fn is not None or key in self._callables:
                 continue
-            fn, ins = build_item_callable(self.g, self.qm, item,
-                                          interpret=self.interpret)
+            fn, ins = build_item_callable(self.g, self.qm, item)
             for _ in range(max(1, self.warmup)):
                 jax.block_until_ready(fn(*ins))
             self._callables[key] = (fn, ins)
@@ -214,7 +212,7 @@ class DriftProfiler:
         key = _unit_key(item)
         if key not in self._callables:
             self._callables[key] = build_item_callable(
-                self.g, self.qm, item, interpret=self.interpret)
+                self.g, self.qm, item)
         fn, ins = self._callables[key]
         seconds, _, _, _, _ = time_callable(fn, ins, warmup=self.warmup,
                                             repeats=self.repeats)

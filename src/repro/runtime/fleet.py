@@ -112,7 +112,7 @@ class Fleet:
     """N data-parallel Session replicas behind one failover front door."""
 
     def __init__(self, artifact, *, n_replicas: int | None = None,
-                 devices=None, backend: str = "ref", interpret: bool = True,
+                 devices=None, backend: str = "ref",
                  max_retries: int = 3, retry_backoff_s: float = 0.01,
                  request_deadline_s: float = 60.0,
                  attempt_timeout_s: float = 10.0,
@@ -193,7 +193,7 @@ class Fleet:
             rid = f"r{i}"
             dev = self.devices[i % len(self.devices)]
             session = Session.from_artifact(
-                artifact, backend=backend, interpret=interpret,
+                artifact, backend=backend,
                 cache=_fresh_plan_cache(), placement=dev, **session_kw)
             server = Server(session,
                             labels={"replica": rid},

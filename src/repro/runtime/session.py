@@ -34,7 +34,7 @@ class Session:
     """Owns the executor + memory plan for one compiled model."""
 
     def __init__(self, g, strategy, dev, qm, *, backend: str = "ref",
-                 cache=None, interpret: bool = True, profile=None,
+                 cache=None, profile=None,
                  pin_input: bool | None = None,
                  cache_max_entries: int | None = None, placement=None):
         """``profile`` names the calibrated device profile to compile under —
@@ -59,7 +59,7 @@ class Session:
         self.graph, self.qm, self.device = g, qm, dev
         self.backend = backend
         self.executor = Int8Executor(g, qm, strategy=self.artifact,
-                                     backend=backend, interpret=interpret)
+                                     backend=backend)
         self.outputs = [n.name for n in g if not g.consumers(n.name)]
         self.n_runs = 0
         self.images_served = 0
@@ -69,7 +69,7 @@ class Session:
 
     @classmethod
     def from_artifact(cls, art, *, backend: str = "ref", cache=None,
-                      interpret: bool = True, profile=None,
+                      profile=None,
                       cache_max_entries: int | None = None,
                       placement=None) -> "Session":
         """Open a session on a loaded DNNVM object file — no recompilation:
@@ -105,7 +105,7 @@ class Session:
         # pipeline_report and the session-side profile_hash provenance
         cache.put(g, art, dev, art, qm=qm, profile=resolved)
         return cls(g, art, dev, qm, backend=backend, cache=cache,
-                   interpret=interpret, profile=resolved,
+                   profile=resolved,
                    cache_max_entries=cache_max_entries, placement=placement)
 
     # ------------------------------------------------------------- execution

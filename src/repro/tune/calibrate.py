@@ -246,7 +246,7 @@ class CalibrationResult:
 def calibrate(g: XGraph, qm, dev: DeviceModel, *,
               groups: list | None = None, harness=None, measure_fn=None,
               backend: str = "pallas", features: str = "kernel",
-              interpret: bool = True, warmup: int = 1, repeats: int = 7,
+              warmup: int = 1, repeats: int = 7,
               max_samples: int = 48, combine: str | None = None,
               name: str | None = None, min_measurable_s: float = 5e-4,
               refit_model: bool = True,
@@ -274,7 +274,7 @@ def calibrate(g: XGraph, qm, dev: DeviceModel, *,
         g, max_samples=max_samples)
     if measure_fn is None and harness is None:
         harness = MeasurementHarness(g, qm, dev, backend=backend,
-                                     interpret=interpret, warmup=warmup,
+                                     warmup=warmup,
                                      repeats=repeats)
 
     measurable, feats, skipped = [], [], []

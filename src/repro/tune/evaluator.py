@@ -69,7 +69,7 @@ def _chain_vec(g: XGraph, launch: lower.FusedLaunch):
     oc = (g.shape(names[last_conv])[3] if conv_pos
           else g.shape(launch.in_name)[3])
     th, tw, toc = _resolve_tile(tuple(launch.tile), oh, ow, oc,
-                                bool(conv_pos))
+                                len(conv_pos))
     geom = chain_geometry(stages, th, oh, ow, tw)
     n = max(1, g.shape(names[-1])[0])
 
@@ -150,7 +150,7 @@ def _horizontal_vec(g: XGraph, launch: lower.FusedLaunch):
     oc = sum(oc_m for _, oc_m, _, _ in launch.members)
     ic = g.shape(launch.in_name)[3]
     n = max(1, g.shape(launch.members[0][0])[0])
-    th, tw, toc = _resolve_tile(tuple(launch.tile), oh, ow, oc, True)
+    th, tw, toc = _resolve_tile(tuple(launch.tile), oh, ow, oc, 1)
     n_h = -(-oh // th)
     n_w = -(-ow // tw)
     cells = n * n_h * n_w * max(1, oc // toc)

@@ -105,7 +105,7 @@ def _rand_int8(rng, shape):
     return jnp.asarray(rng.integers(-128, 128, shape), jnp.int8)
 
 
-def build_item_callable(g: XGraph, qm, item, *, interpret: bool = True):
+def build_item_callable(g: XGraph, qm, item):
     """One ``GroupProgram`` item as a standalone jitted callable + inputs.
 
     ``FusedLaunch`` entries run the actual Pallas chain/horizontal kernel;
@@ -126,7 +126,7 @@ def build_item_callable(g: XGraph, qm, item, *, interpret: bool = True):
     @jax.jit
     def fn(*xs):
         env = dict(zip(in_names, xs))
-        out = fused_ops.run_launch(item, env, qm, interpret=interpret)
+        out = fused_ops.run_launch(item, env, qm)
         return tuple(out[k] for k in sorted(out))
 
     return fn, ins
@@ -143,7 +143,7 @@ class MeasurementHarness:
     """
 
     def __init__(self, g: XGraph, qm, dev: DeviceModel | None = None, *,
-                 backend: str = "pallas", interpret: bool = True,
+                 backend: str = "pallas",
                  warmup: int = 1, repeats: int = 12,
                  reject_nmad: float = 3.5, min_sample_s: float = 0.0,
                  center: str = "min"):
@@ -153,7 +153,6 @@ class MeasurementHarness:
             raise ValueError(f"unknown center {center!r}")
         self.g, self.qm, self.dev = g, qm, dev
         self.backend = backend
-        self.interpret = interpret
         self.warmup, self.repeats = warmup, repeats
         self.reject_nmad = reject_nmad
         self.min_sample_s = min_sample_s
@@ -178,8 +177,7 @@ class MeasurementHarness:
             item = self._lower_chain(group)
             kind = (item.kind if isinstance(item, lower.FusedLaunch)
                     else "fallback")
-            fn, ins = build_item_callable(self.g, self.qm, item,
-                                          interpret=self.interpret)
+            fn, ins = build_item_callable(self.g, self.qm, item)
         else:
             kind = "fallback"
             fn, ins = core_executor.build_group_callable(
@@ -190,8 +188,7 @@ class MeasurementHarness:
     def measure_item(self, item) -> Measurement:
         kind = (item.kind if isinstance(item, lower.FusedLaunch)
                 else "fallback")
-        fn, ins = build_item_callable(self.g, self.qm, item,
-                                      interpret=self.interpret)
+        fn, ins = build_item_callable(self.g, self.qm, item)
         return self._time(fn, ins, item.nodes, kind)
 
     def measure_group(self, group: list) -> Measurement:
@@ -272,8 +269,7 @@ class MeasurementHarness:
         for item in items:
             kind = (item.kind if isinstance(item, lower.FusedLaunch)
                     else "fallback")
-            fn, ins = build_item_callable(self.g, self.qm, item,
-                                          interpret=self.interpret)
+            fn, ins = build_item_callable(self.g, self.qm, item)
             units.append((item.nodes, kind, fn, ins))
         return self._round_robin(units, passes)
 
@@ -306,8 +302,7 @@ class MeasurementHarness:
         """Wall-clock one full strategy through ``Int8Executor`` (the e2e
         number the tune benchmark compares across search evaluators)."""
         ex = core_executor.Int8Executor(self.g, self.qm, strategy=strategy,
-                                        backend=self.backend,
-                                        interpret=self.interpret)
+                                        backend=self.backend)
         rng = np.random.default_rng(seed)
         shape = next(self.g.shape(n.name) for n in self.g if n.op == "input")
         x = rng.integers(-128, 128, shape).astype(np.int8)
@@ -337,8 +332,7 @@ class MeasurementHarness:
         units = []
         for s in strategies:
             ex = core_executor.Int8Executor(self.g, self.qm, strategy=s,
-                                            backend=self.backend,
-                                            interpret=self.interpret)
+                                            backend=self.backend)
             for _ in range(max(1, self.warmup)):
                 _run(ex, x)
             t0 = time.perf_counter()

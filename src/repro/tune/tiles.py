@@ -55,9 +55,31 @@ def default_shape(g: XGraph, item: lower.FusedLaunch) -> tuple:
     from repro.kernels.conv_fused.ops import _resolve_tile
 
     oh, ow = item.out_hw
-    has_conv = (item.kind == "horizontal"
-                or any(st[0] == "conv" for st in item.stages))
-    return _resolve_tile((), oh, ow, launch_oc(g, item), has_conv)
+    return _resolve_tile((), oh, ow, launch_oc(g, item), _n_conv(item))
+
+
+def _n_conv(item: lower.FusedLaunch) -> int:
+    if item.kind == "horizontal":
+        return 1
+    return sum(1 for st in item.stages if st[0] == "conv")
+
+
+def _legal_shapes(g: XGraph, item: lower.FusedLaunch, shapes) -> list:
+    """Candidates the chip's block rule admits (``ops._resolve_tile``): T_w
+    rounded to a multiple of 8 or the full width, T_oc a 128-multiple
+    divisor of OC or OC itself; duplicates after rounding dropped."""
+    from repro.kernels.conv_fused.ops import _legal_toc, _legal_tw
+
+    oc, n_conv = launch_oc(g, item), _n_conv(item)
+    out, seen = [], set()
+    for th, tw, toc in shapes:
+        if n_conv and not _legal_toc(int(toc), oc):
+            continue
+        s = (int(th), _legal_tw(int(tw), item.out_hw[1]), int(toc))
+        if s not in seen:
+            seen.add(s)
+            out.append(s)
+    return out
 
 
 def analytic_shape(g: XGraph, dev: DeviceModel,
@@ -65,11 +87,14 @@ def analytic_shape(g: XGraph, dev: DeviceModel,
     """The paper's Eq. 5/6 shape for this launch's node cover (T_h/T_oc
     pinned to the array parallelism, maximal T_w) — always part of the
     measured candidate set, so the tile search can never do worse than the
-    analytic solution it generalizes."""
+    analytic solution it generalizes.  None when it is infeasible or its
+    T_oc breaks the chip's block rule."""
     t = (tiling.solve_horizontal(g, list(item.nodes), dev)
          if item.kind == "horizontal"
          else tiling.solve(g, list(item.nodes), dev))
-    return (t.t_h, t.t_w, t.t_oc) if t.feasible else None
+    legal = _legal_shapes(g, item, [(t.t_h, t.t_w, t.t_oc)]) \
+        if t.feasible else []
+    return legal[0] if legal else None
 
 
 def shape_candidates(g: XGraph, dev: DeviceModel, item: lower.FusedLaunch,
@@ -97,10 +122,10 @@ def shape_candidates(g: XGraph, dev: DeviceModel, item: lower.FusedLaunch,
                     if (th, w, toc) not in seen:
                         seen.add((th, w, toc))
                         shapes.append((th, w, toc))
-        return shapes[:max_candidates]
+        return _legal_shapes(g, item, shapes)[:max_candidates]
     cands = tiling.enumerate_tilings(g, list(item.nodes), dev,
                                      max_candidates=max_candidates)
-    return [(t.t_h, t.t_w, t.t_oc) for t in cands]
+    return _legal_shapes(g, item, [(t.t_h, t.t_w, t.t_oc) for t in cands])
 
 
 def predict_shape_seconds(profile: DeviceProfile, g: XGraph,

@@ -1,5 +1,6 @@
 """conv_fused Pallas kernel: bit-exact vs the int8 oracle across a
-shape/stride/pool/eltwise sweep (interpret mode)."""
+shape/stride/pool/eltwise sweep (interpret mode on the CPU, compiled on
+a chip)."""
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -11,7 +12,8 @@ try:  # dev-only dep (requirements-dev.txt); only the property sweep needs it
 except ImportError:
     HAVE_HYPOTHESIS = False
 
-from repro.kernels.conv_fused.ops import fused_conv_block, supports
+from repro.kernels.conv_fused.ops import (fused_conv_block, interpret_mode,
+                                          supports)
 from repro.kernels.conv_fused.ref import fused_conv_ref
 
 
@@ -118,7 +120,7 @@ def test_dilated_conv_bit_exact():
                            dilation=(2, 2), shift=6, relu=True)
     chain = (("conv", "c", 3, 3, 1, 1, 2, 2, 2, 2, 6, True, 12, 12),)
     got = _run_chain(x, (wt,), (b,), (), chain=chain, oh=12, ow=12, oc=8,
-                     interpret=True)
+                     interpret=interpret_mode())
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
@@ -141,7 +143,7 @@ def test_ceil_pool_chain_bit_exact():
         chain = (("conv", "c", 3, 3, 1, 1, 1, 1, 1, 1, 6, True, 13, 13),
                  ("pool", "p", "max", kp, kp, sp, sp, pp, pp, oh, oh, kp * kp))
         got = _run_chain(x, (wt,), (b,), (), chain=chain, oh=oh, ow=oh,
-                         oc=8, interpret=True)
+                         oc=8, interpret=interpret_mode())
         np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
@@ -157,7 +159,7 @@ def test_avgpool_chain_bit_exact():
     chain = (("conv", "c", 3, 3, 1, 1, 1, 1, 1, 1, 6, True, 12, 12),
              ("pool", "p", "avg", 2, 2, 2, 2, 0, 0, 6, 6, 4))
     got = _run_chain(x, (wt,), (b,), (), chain=chain, oh=6, ow=6, oc=8,
-                     interpret=True)
+                     interpret=interpret_mode())
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
@@ -182,6 +184,6 @@ def test_horizontal_stacked_bit_exact():
         x, jnp.concatenate([wa, wb], axis=-1), jnp.concatenate([ba, bb]),
         jnp.asarray(np.repeat([5, 7], [8, 12]).astype(np.int32)),
         jnp.asarray(np.repeat([1, 0], [8, 12]).astype(np.int32)),
-        stride=(1, 1), pad=(1, 1), oh=10, ow=10, interpret=True)
+        stride=(1, 1), pad=(1, 1), oh=10, ow=10, interpret=interpret_mode())
     np.testing.assert_array_equal(np.asarray(y[..., :8]), np.asarray(ya))
     np.testing.assert_array_equal(np.asarray(y[..., 8:]), np.asarray(yb))

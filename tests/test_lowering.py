@@ -65,6 +65,20 @@ def test_small_strategies_never_fall_back_silently(model, img):
     assert covered == set(g.compute_nodes())
 
 
+@pytest.mark.parametrize("model", ["resnet50", "resnet152"])
+def test_resnet_logits_form_lowers_without_fallback(model):
+    """``softmax=False`` ends a ResNet at the fc logits: under the TPU plan
+    every node then runs in a fused launch (softmax is a host op)."""
+    from repro.hw import TPU_V5E
+
+    g = build(model, img=32, num_classes=10, softmax=False)
+    assert [n.name for n in g if not g.consumers(n.name)] == ["fc"]
+    prog = lower.lower_strategy(g, pathsearch.search(g, TPU_V5E))
+    assert not prog.fallbacks()
+    assert {n for it in prog.launches() for n in it.nodes} == \
+        set(g.compute_nodes())
+
+
 # ------------------------------------------------- bit-exactness per kind
 def test_conv_eltwise_maxpool_chain_bit_exact(rng):
     g = XGraph("cep")

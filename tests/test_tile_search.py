@@ -13,6 +13,7 @@ import pytest
 from repro.core import executor, int8_ops, lower, pathsearch, quantize, tiling
 from repro.core.xgraph import XGraph
 from repro.hw import TPU_V5E, ZU2
+from repro.kernels.conv_fused.ops import interpret_mode
 from tests.conftest import make_toy_resnet_graph, toy_params
 
 
@@ -93,87 +94,114 @@ def _conv_data(rng, h, w, ic, oc, k):
     return x, wt, b
 
 
-@pytest.mark.parametrize("h,k,s,d,tile", [
-    (13, 3, 1, 1, (4, 5, 4)),    # ragged right edge (13 % 5 != 0)
-    (12, 3, 2, 1, (3, 2, 8)),    # stride-2 halo between width tiles
-    (12, 3, 1, 2, (5, 3, 2)),    # dilated halo
-    (11, 5, 2, 1, (2, 3, 8)),    # 5x5 stride-2, everything ragged
+def _grid_split(tile, oh, ow, oc, n_conv=1) -> tuple:
+    """(width tiles, OC tiles) the launch runs ``tile`` with; the shape must
+    already be legal for the chip, so the launcher runs it unchanged."""
+    from repro.kernels.conv_fused.ops import _resolve_tile
+
+    th, tw, toc = _resolve_tile(tile, oh, ow, oc, n_conv)
+    assert (th, tw, toc) == tuple(tile), "tile was legalized"
+    return -(-ow // tw), oc // toc
+
+
+@pytest.mark.parametrize("h,k,s,d,oc,tile", [
+    (19, 3, 1, 1, 256, (4, 8, 128)),  # ragged right edge (19 % 8 != 0)
+    (34, 3, 2, 1, 8, (3, 8, 8)),      # stride-2 halo between width tiles
+    (20, 3, 1, 2, 8, (5, 8, 8)),      # dilated halo
+    (35, 5, 2, 1, 8, (4, 8, 8)),      # 5x5 stride-2, everything ragged
 ])
-def test_width_tiled_conv_bit_exact(h, k, s, d, tile):
+def test_width_tiled_conv_bit_exact(h, k, s, d, oc, tile):
     from repro.kernels.conv_fused.ops import _run_chain
 
     rng = np.random.default_rng(h * k + s)
-    x, wt, b = _conv_data(rng, h, h, 4, 8, k)
+    x, wt, b = _conv_data(rng, h, h, 4, oc, k)
     p = d * (k - 1) // 2
     oh = (h + 2 * p - (d * (k - 1) + 1)) // s + 1
+    n_w, n_oc = _grid_split(tile, oh, oh, oc)
+    assert n_w > 1 and (n_oc > 1 or oc == tile[2])
     want = int8_ops.conv2d(x, wt, b, stride=(s, s), pad=(p, p),
                            dilation=(d, d), shift=6, relu=True)
     chain = (("conv", "c", k, k, s, s, p, p, d, d, 6, True, oh, oh),)
-    got = _run_chain(x, (wt,), (b,), (), chain=chain, oh=oh, ow=oh, oc=8,
-                     interpret=True, tile=tile)
+    got = _run_chain(x, (wt,), (b,), (), chain=chain, oh=oh, ow=oh, oc=oc,
+                     interpret=interpret_mode(), tile=tile)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
 def test_width_tiled_pool_tail_chain_bit_exact():
-    """conv -> ceil-mode maxpool across width tiles: the padded-coordinate
-    masking must hold at interior tile boundaries, not just the right edge."""
+    """conv -> ceil-mode maxpool across width and OC tiles: the padded-
+    coordinate masking must hold at interior tile boundaries, not just the
+    right edge, and the pool rides the final conv's OC slice."""
     from repro.kernels.conv_fused.ops import _run_chain
     from repro.kernels.conv_fused.ref import fused_conv_ref
 
     rng = np.random.default_rng(5)
-    x, wt, b = _conv_data(rng, 13, 13, 4, 8, 3)
+    x, wt, b = _conv_data(rng, 33, 33, 4, 256, 3)
     y_c = fused_conv_ref(x, wt, b, stride=(1, 1), pad=(1, 1), shift=6,
                          relu=True)
     for kp, sp, pp in [(3, 2, 0), (3, 2, 1), (2, 2, 1)]:
         want = int8_ops.maxpool(y_c, kernel=(kp, kp), stride=(sp, sp),
                                 pad=(pp, pp), ceil_mode=True)
-        oh = math.ceil((13 + 2 * pp - kp) / sp) + 1
-        chain = (("conv", "c", 3, 3, 1, 1, 1, 1, 1, 1, 6, True, 13, 13),
+        oh = math.ceil((33 + 2 * pp - kp) / sp) + 1     # 16, 17, 18
+        chain = (("conv", "c", 3, 3, 1, 1, 1, 1, 1, 1, 6, True, 33, 33),
                  ("pool", "p", "max", kp, kp, sp, sp, pp, pp, oh, oh, kp * kp))
-        for tile in [(2, 3, 4), (3, 2, 2), (oh, oh, 8)]:
+        for tile, split in [((2, 8, 128), (True, True)),
+                            ((3, 8, 256), (True, False)),
+                            ((oh, oh, 256), (False, False))]:
+            n_w, n_oc = _grid_split(tile, oh, oh, 256)
+            assert (n_w > 1, n_oc > 1) == split
             got = _run_chain(x, (wt,), (b,), (), chain=chain, oh=oh, ow=oh,
-                             oc=8, interpret=True, tile=tile)
+                             oc=256, interpret=interpret_mode(), tile=tile)
             np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
 def test_width_tiled_eltwise_chain_bit_exact():
-    """conv -> eltwise_add: the side input rides the same width tiling."""
+    """conv -> eltwise_add: the side input rides the same width tiling and
+    the final conv's OC slice."""
     from repro.kernels.conv_fused.ops import _run_chain
 
     rng = np.random.default_rng(7)
-    x, wt, b = _conv_data(rng, 10, 10, 4, 8, 3)
-    side = jnp.asarray(rng.integers(-128, 128, (1, 10, 10, 8)).astype(np.int8))
+    x, wt, b = _conv_data(rng, 20, 20, 4, 256, 3)
+    side = jnp.asarray(rng.integers(-128, 128, (1, 20, 20, 256))
+                       .astype(np.int8))
     y_c = int8_ops.conv2d(x, wt, b, stride=(1, 1), pad=(1, 1), shift=6)
     want = int8_ops.eltwise_add([y_c, side], [1, 2], 0, relu=True)
-    chain = (("conv", "c", 3, 3, 1, 1, 1, 1, 1, 1, 6, False, 10, 10),
-             ("elt", "e", 1, 2, True, 10, 10))
-    for tile in [(4, 3, 8), (3, 4, 4), (10, 7, 2)]:
-        got = _run_chain(x, (wt,), (b,), (side,), chain=chain, oh=10, ow=10,
-                         oc=8, interpret=True, tile=tile)
+    chain = (("conv", "c", 3, 3, 1, 1, 1, 1, 1, 1, 6, False, 20, 20),
+             ("elt", "e", 1, 2, True, 20, 20))
+    for tile in [(4, 8, 128), (3, 8, 256), (7, 16, 128)]:
+        n_w, n_oc = _grid_split(tile, 20, 20, 256)
+        assert n_w > 1 and n_oc == 256 // tile[2]
+        got = _run_chain(x, (wt,), (b,), (side,), chain=chain, oh=20, ow=20,
+                         oc=256, interpret=interpret_mode(), tile=tile)
         np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
 def test_width_tiled_horizontal_bit_exact():
+    """Two stacked siblings (64 + 192 OC): a 128-lane OC tile straddles the
+    member boundary, so the per-channel shift/ReLU rows must follow it."""
     from repro.kernels.conv_fused.ops import _run_horizontal
 
     rng = np.random.default_rng(9)
-    x = jnp.asarray(rng.integers(-128, 128, (1, 11, 11, 4)).astype(np.int8))
-    wa = jnp.asarray(rng.integers(-128, 128, (3, 3, 4, 8)).astype(np.int8))
-    wb = jnp.asarray(rng.integers(-128, 128, (3, 3, 4, 12)).astype(np.int8))
-    ba = jnp.asarray(rng.integers(-2000, 2000, 8).astype(np.int32))
-    bb = jnp.asarray(rng.integers(-2000, 2000, 12).astype(np.int32))
+    x = jnp.asarray(rng.integers(-128, 128, (1, 19, 19, 4)).astype(np.int8))
+    wa = jnp.asarray(rng.integers(-128, 128, (3, 3, 4, 64)).astype(np.int8))
+    wb = jnp.asarray(rng.integers(-128, 128, (3, 3, 4, 192)).astype(np.int8))
+    ba = jnp.asarray(rng.integers(-2000, 2000, 64).astype(np.int32))
+    bb = jnp.asarray(rng.integers(-2000, 2000, 192).astype(np.int32))
     ya = int8_ops.conv2d(x, wa, ba, stride=(1, 1), pad=(1, 1), shift=5,
                          relu=True)
     yb = int8_ops.conv2d(x, wb, bb, stride=(1, 1), pad=(1, 1), shift=7)
-    for tile in [(3, 4, 20), (4, 7, 10), (11, 11, 4)]:   # 11 % 4, 11 % 7 != 0
+    for tile, split in [((3, 8, 128), (True, True)),     # 19 % 8 != 0
+                        ((4, 16, 256), (True, False)),
+                        ((19, 19, 128), (False, True))]:
+        n_w, n_oc = _grid_split(tile, 19, 19, 256)
+        assert (n_w > 1, n_oc > 1) == split
         y = _run_horizontal(
             x, jnp.concatenate([wa, wb], axis=-1), jnp.concatenate([ba, bb]),
-            jnp.asarray(np.repeat([5, 7], [8, 12]).astype(np.int32)),
-            jnp.asarray(np.repeat([1, 0], [8, 12]).astype(np.int32)),
-            stride=(1, 1), pad=(1, 1), oh=11, ow=11, interpret=True,
+            jnp.asarray(np.repeat([5, 7], [64, 192]).astype(np.int32)),
+            jnp.asarray(np.repeat([1, 0], [64, 192]).astype(np.int32)),
+            stride=(1, 1), pad=(1, 1), oh=19, ow=19, interpret=interpret_mode(),
             tile=tile)
-        np.testing.assert_array_equal(np.asarray(y[..., :8]), np.asarray(ya))
-        np.testing.assert_array_equal(np.asarray(y[..., 8:]), np.asarray(yb))
+        np.testing.assert_array_equal(np.asarray(y[..., :64]), np.asarray(ya))
+        np.testing.assert_array_equal(np.asarray(y[..., 64:]), np.asarray(yb))
 
 
 # ----------------------------------------------------- lowering + execution
