@@ -52,6 +52,7 @@ class FlightRecord:
     status: str                   # "ok" | "error" | "rejected"
     error: str | None = None
     drift: dict | None = None     # tenant drift summary at record time
+    inflight_at_dispatch: int = 0  # earlier batches uncollected at dispatch
 
     def to_json(self) -> dict:
         d = dataclasses.asdict(self)
@@ -118,14 +119,15 @@ class FlightRecorder:
                execute_s: float = 0.0, latency_s: float = 0.0,
                batch_id: int = -1, batch_size: int = 0,
                batch_members=(), status: str = "ok",
-               error: str | None = None, drift: dict | None = None
-               ) -> FlightRecord:
+               error: str | None = None, drift: dict | None = None,
+               inflight_at_dispatch: int = 0) -> FlightRecord:
         rec = FlightRecord(req_id=req_id, tenant=tenant, submit_s=submit_s,
                            queue_wait_s=queue_wait_s, execute_s=execute_s,
                            latency_s=latency_s, batch_id=batch_id,
                            batch_size=batch_size,
                            batch_members=tuple(batch_members), status=status,
-                           error=error, drift=drift)
+                           error=error, drift=drift,
+                           inflight_at_dispatch=inflight_at_dispatch)
         with self._lock:
             self._records.append(rec)
             self.n_recorded += 1

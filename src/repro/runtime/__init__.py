@@ -5,10 +5,12 @@ and a validation environment"; this package is the runtime supporter — the
 host-side layer that feeds the accelerator:
 
 * :class:`Session`         — owns one compiled model (artifact via PlanCache,
-                             executor, memory plan); ``run`` / ``run_batch``.
+                             executor, memory plan); ``run`` / ``run_batch``
+                             / ``launch`` (dispatch without waiting).
 * :class:`DynamicBatcher`  — async request queue with max-batch / max-latency
-                             knobs; one worker flushes queued images as one
-                             batched launch.
+                             knobs; a worker dispatches queued images as one
+                             batched launch while the previous batch runs,
+                             a collector resolves each batch's futures.
 * :class:`Server`          — Session + batcher + latency/batch metrics.
 * :class:`MultiServer`     — many models on one device: DDR partitioning,
                              per-tenant SLO classes, admission control.

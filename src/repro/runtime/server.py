@@ -4,8 +4,9 @@
 future for its output dict.  Internally queued requests are flushed as
 batches (see :mod:`repro.runtime.batching`), each batch padded up to the
 nearest *allowed* size so the jitted executor only ever traces a handful of
-batch shapes, and every completion is timestamped for the latency
-percentiles the serving benchmark reports.
+batch shapes and dispatched (``Session.launch``) while the batch before it
+may still run on the device, and every completion is timestamped for the
+latency percentiles the serving benchmark reports.
 """
 from __future__ import annotations
 
@@ -105,8 +106,10 @@ class Server:
         return n
 
     def _run(self, xs):
+        """The batcher's runner: dispatch the batch and hand back the
+        session's pending batch, which the batcher's collector waits on."""
         self._adjust_for_slo()
-        return self.session.run_batch(xs, pad_to=self._pad_size(len(xs)))
+        return self.session.launch(xs, pad_to=self._pad_size(len(xs)))
 
     # ------------------------------------------------- SLO-aware batch cap
     @property
@@ -125,7 +128,8 @@ class Server:
         """p99 over the freshest ``n_fresh`` samples of the bounded window —
         never over latencies recorded before the last cap change, which
         describe a batch size that no longer exists."""
-        lats = list(self._batcher.latencies)[-min(self._slo_window, n_fresh):]
+        lats = self._batcher.samples()["latencies"][
+            -min(self._slo_window, n_fresh):]
         if len(lats) < 4:
             return None
         return self._p99_ms(lats)
@@ -134,16 +138,18 @@ class Server:
         """Which half of the fresh latency window dominates its p99: the
         per-request queue wait or the batched launch."""
         k = min(self._slo_window, n_fresh)
-        wait = self._p99_ms(list(self._batcher.queue_waits)[-k:]) or 0.0
-        execute = self._p99_ms(list(self._batcher.execute_s)[-k:]) or 0.0
+        s = self._batcher.samples()
+        wait = self._p99_ms(s["queue_waits"][-k:]) or 0.0
+        execute = self._p99_ms(s["execute_s"][-k:]) or 0.0
         return "queue" if wait > execute else "launch"
 
     def _adjust_for_slo(self) -> None:
         """Runs on the batcher worker before each launch (single-threaded
-        with batch formation, so the cap never changes mid-batch).  Each cap
-        change starts a cooldown: no further move until enough requests have
-        been served *under the new cap* to judge it — otherwise one transient
-        violation cascades the cap straight to the floor on stale samples."""
+        with batch formation, so the cap never changes mid-batch; the
+        collector's samples are read as copies).  Each cap change starts a
+        cooldown: no further move until enough requests have been served
+        *under the new cap* to judge it — otherwise one transient violation
+        cascades the cap straight to the floor on stale samples."""
         if self.target_p99_ms is None:
             return
         cur = self._batcher.max_batch
@@ -239,12 +245,13 @@ class Server:
 
     # --------------------------------------------------------------- metrics
     def stats(self) -> dict:
-        lats = sorted(self._batcher.latencies)
+        s = self._batcher.samples()
+        lats = sorted(s["latencies"])
         pct = (lambda q: lats[min(len(lats) - 1,
                                   int(q * (len(lats) - 1)))] * 1e3) \
             if lats else (lambda q: 0.0)
-        hist = dict(sorted(self._batcher.batch_sizes.items()))
-        n = self._batcher.n_served
+        hist = dict(sorted(s["batch_sizes"].items()))
+        n = s["n_served"]
         return {
             "n_served": n,
             "n_batches": sum(hist.values()),
@@ -252,8 +259,8 @@ class Server:
             "mean_batch": (n / sum(hist.values())) if hist else 0.0,
             "p50_ms": pct(0.50),
             "p99_ms": pct(0.99),
-            "queue_wait_p99_ms": self._p99_ms(self._batcher.queue_waits),
-            "execute_p99_ms": self._p99_ms(self._batcher.execute_s),
+            "queue_wait_p99_ms": self._p99_ms(s["queue_waits"]),
+            "execute_p99_ms": self._p99_ms(s["execute_s"]),
             "allowed_sizes": list(self.allowed_sizes),
             "target_p99_ms": self.target_p99_ms,
             "effective_max_batch": self.effective_max_batch,

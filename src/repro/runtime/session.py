@@ -15,10 +15,34 @@ one (graph, strategy, device, quantization) tuple:
 batched launch (one Pallas grid covers all N images — the executor's batch
 dimension is free); ``serve`` wraps the session in the dynamic-batching
 :class:`~repro.runtime.server.Server`.
+
+A batch runs in two phases.  ``launch`` stacks the requests, copies the
+batch in and enqueues the program, and returns a :class:`PendingBatch`
+without waiting for the device; ``PendingBatch.results`` waits for the
+device, copies the outputs out and slices one dict per request.  The
+server's batcher dispatches the next batch between the two, so the host's
+stacking and copy-in overlap the device's run of the batch before it;
+``run_batch`` is the two phases back to back.
 """
 from __future__ import annotations
 
 import numpy as np
+
+
+class PendingBatch:
+    """A dispatched batch whose outputs are still on the device."""
+
+    def __init__(self, executor, batch, n: int):
+        self._executor = executor
+        self._batch = batch             # executor.Dispatched
+        self.n = n                      # requests (rows before padding)
+
+    def results(self) -> list[dict]:
+        """Wait for the device; one output dict per request (leading batch
+        dim 1, directly comparable with per-request execution)."""
+        host = self._executor(self._batch)
+        return [{k: v[i:i + 1] for k, v in host.items()}
+                for i in range(self.n)]
 
 
 def _resolve_profile(profile):
@@ -134,18 +158,20 @@ class Session:
         hang, slow, or poison one replica deterministically."""
         self._launch_hook = fn
 
-    def _launch(self, x):
+    def _launch(self, x, wait: bool = True):
         """One executor launch, through the hook and onto the placement
-        device (``jax.default_device``; a no-op for the numpy ref backend's
-        compute, but keeps any jax arrays the launch creates on the replica's
-        device)."""
+        device (``jax.default_device``, so the jit cache key and the arrays
+        the launch creates are the replica's).  ``wait``: the executor's
+        call, outputs on the host; else its dispatch, outputs still on the
+        device."""
         if self._launch_hook is not None:
             self._launch_hook(x)
+        run = self.executor if wait else self.executor.dispatch
         if self.placement is None:
-            return self.executor(x)
+            return run(x)
         import jax
         with jax.default_device(self.placement):
-            return self.executor(x)
+            return run(x)
 
     def drift_state(self) -> dict | None:
         """The attached profiler's most recent summary (None when no drift
@@ -181,22 +207,29 @@ class Session:
             self.drift.observe_launch()
         return out
 
-    def run_batch(self, xs, pad_to: int | None = None) -> list[dict]:
-        """Serve N queued requests as ONE batched launch; returns one output
-        dict per request (leading batch dim 1, so results are directly
-        comparable with per-request execution)."""
+    def launch(self, xs, pad_to: int | None = None) -> PendingBatch:
+        """Stack N queued requests (zero-padded to ``pad_to``) and dispatch
+        them as ONE batched launch, without waiting for the device.  The
+        ``launch`` span covers the copy-in and the dispatch only."""
+        from repro.core.executor import Dispatched
         from repro.obs.trace import TRACER
         with TRACER.span("pad", cat="serve", track="batch", n=len(xs),
                          pad_to=pad_to):
             x, n = self._stack(xs, pad_to=pad_to)
         with TRACER.span("launch", cat="serve", track="batch",
                          batch=int(x.shape[0])):
-            out = self._launch(x)
+            out = self._launch(x, wait=False)
         self.n_runs += 1
         self.images_served += n
         if self.drift is not None:
             self.drift.observe_launch()
-        return [{k: v[i:i + 1] for k, v in out.items()} for i in range(n)]
+        return PendingBatch(self.executor, Dispatched(x, out), n)
+
+    def run_batch(self, xs, pad_to: int | None = None) -> list[dict]:
+        """Serve N queued requests as ONE batched launch and wait for it;
+        returns one output dict per request (leading batch dim 1, so results
+        are directly comparable with per-request execution)."""
+        return self.launch(xs, pad_to=pad_to).results()
 
     # -------------------------------------------------------- schedule view
     def pipeline_report(self, n_requests: int, ddr_slots: int | None = 2,

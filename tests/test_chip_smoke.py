@@ -40,18 +40,18 @@ def test_served_path_bit_exact_without_fallbacks(toy):
 
 def test_served_path_flags_a_wrong_output(toy, monkeypatch):
     """The bit-exactness check is live: a served output one bit off fails."""
-    from repro.runtime import Session
+    from repro.runtime.session import PendingBatch
 
     g, qm, xs = toy
-    real = Session.run_batch
+    real = PendingBatch.results
 
-    def off_by_one(self, batch, pad_to=None):
-        outs = real(self, batch, pad_to=pad_to)
-        k = self.outputs[-1]
+    def off_by_one(self):
+        outs = real(self)
+        k = max(outs[0])
         outs[0] = dict(outs[0], **{k: outs[0][k] ^ 1})
         return outs
 
-    monkeypatch.setattr(Session, "run_batch", off_by_one)
+    monkeypatch.setattr(PendingBatch, "results", off_by_one)
     with pytest.raises(chip_smoke.SmokeError, match="differ"):
         chip_smoke.serve_one_chip(g, qm, xs, log=lambda s: None)
 

@@ -152,6 +152,175 @@ def test_batcher_propagates_executor_failure():
             fut.result(timeout=10)
 
 
+# ------------------------------------- double-buffered dispatch and collect
+class _TwoPhase:
+    """A fake two-phase runner: each call dispatches a batch and returns a
+    pending batch whose ``results()`` waits on that batch's own gate.
+    ``fail`` = (phase, batch index) raises in that phase of that batch."""
+
+    def __init__(self, fail=None, open_gates=False):
+        self.lock = threading.Lock()
+        self.fail = fail
+        self.open_gates = open_gates
+        self.sizes = []            # batch sizes, in dispatch order
+        self.gates = []            # one Event per dispatched batch
+        self.collected = []        # batch indices, as results() returns
+        self.uncollected = 0
+        self.max_uncollected = 0
+
+    def __call__(self, xs):
+        with self.lock:
+            i = len(self.sizes)
+            self.sizes.append(len(xs))
+            gate = threading.Event()
+            if self.open_gates:
+                gate.set()
+            self.gates.append(gate)
+            if self.fail == ("dispatch", i):
+                raise RuntimeError(f"dispatch of batch {i} failed")
+            self.uncollected += 1
+            self.max_uncollected = max(self.max_uncollected, self.uncollected)
+        return _Pending(self, i, list(xs))
+
+    def release(self, i=None):
+        """Open batch ``i``'s gate, or every gate now and later."""
+        with self.lock:
+            if i is None:
+                self.open_gates = True
+            for g in (self.gates if i is None else [self.gates[i]]):
+                g.set()
+
+    def wait_dispatched(self, n, timeout=10.0):
+        t_end = time.monotonic() + timeout
+        while len(self.sizes) < n and time.monotonic() < t_end:
+            time.sleep(0.001)
+        return len(self.sizes)
+
+
+class _Pending:
+    def __init__(self, runner, i, xs):
+        self.runner, self.i, self.xs = runner, i, xs
+
+    def results(self):
+        r = self.runner
+        assert r.gates[self.i].wait(timeout=10)
+        with r.lock:
+            r.uncollected -= 1
+            r.collected.append(self.i)
+        if r.fail == ("collect", self.i):
+            raise RuntimeError(f"collect of batch {self.i} failed")
+        return [x * 10 for x in self.xs]
+
+
+def _batcher(runner, **kw):
+    from repro.obs.metrics import MetricsRegistry
+    recs = []
+    kw.setdefault("max_batch", 2)
+    kw.setdefault("max_latency_s", 0.005)
+    b = DynamicBatcher(runner, registry=MetricsRegistry(),
+                       observers=[recs.append], **kw)
+    return b, recs
+
+
+def test_batcher_dispatches_next_batch_before_collecting_last():
+    runner = _TwoPhase()
+    b, recs = _batcher(runner)
+    try:
+        futs = [b.submit(i) for i in range(4)]
+        assert runner.wait_dispatched(2) == 2
+        assert runner.collected == []           # batch 2 went out first
+        runner.release()
+        assert [f.result(timeout=10) for f in futs] == [0, 10, 20, 30]
+    finally:
+        b.close()
+    by_batch = {r["batch_id"]: r["inflight_at_dispatch"] for r in recs}
+    assert sorted(by_batch.values()) == [0, 1]
+    overlapped = b._registry.counter("serve.batches_overlapped").value
+    assert overlapped == 1 and b._m_batches.value == 2
+
+
+def test_batcher_holds_at_most_two_uncollected_and_resolves_in_order():
+    runner = _TwoPhase()
+    b, recs = _batcher(runner)
+    order = []
+    try:
+        futs = [b.submit(i) for i in range(10)]
+        for i, f in enumerate(futs):
+            f.add_done_callback(lambda _, i=i: order.append(i))
+        assert runner.wait_dispatched(2) == 2
+        time.sleep(0.1)
+        assert len(runner.sizes) == 2           # the third waits for a slot
+        runner.release(1)                       # the later batch ends first
+        time.sleep(0.05)
+        assert order == [] and len(runner.sizes) == 2
+        runner.release()
+        assert [f.result(timeout=10) for f in futs] == [
+            i * 10 for i in range(10)]
+    finally:
+        b.close()
+    assert runner.max_uncollected == 2
+    assert order == list(range(10))             # futures in submit order
+    assert runner.collected == list(range(5))
+
+
+def test_batcher_holds_partial_batch_while_one_is_in_flight():
+    runner = _TwoPhase()
+    b, recs = _batcher(runner, max_batch=4, max_latency_s=0.01)
+    try:
+        full = [b.submit(i) for i in range(4)]
+        assert runner.wait_dispatched(1) == 1
+        late = b.submit(4)
+        time.sleep(0.2)                         # 20 deadlines
+        assert runner.sizes == [4]              # held: the device is busy
+        runner.release(0)
+        assert runner.wait_dispatched(2) == 2   # flushed once batch 1 ended
+        assert runner.sizes == [4, 1]
+        runner.release()
+        assert late.result(timeout=10) == 40
+        t0 = time.monotonic()
+        alone = b.submit(5)                     # nothing in flight: deadline
+        assert alone.result(timeout=10) == 50
+        assert time.monotonic() - t0 < 5.0
+        assert [f.result(timeout=10) for f in full] == [0, 10, 20, 30]
+    finally:
+        b.close()
+    assert runner.sizes == [4, 1, 1]
+    assert {r["req_id"]: r["inflight_at_dispatch"] for r in recs}[5] == 0
+
+
+@pytest.mark.parametrize("phase", ["dispatch", "collect"])
+def test_batcher_failure_fails_only_its_own_batch(phase):
+    runner = _TwoPhase(fail=(phase, 1), open_gates=True)
+    b, recs = _batcher(runner)
+    try:
+        futs = [b.submit(i) for i in range(6)]
+        for i in (2, 3):
+            with pytest.raises(RuntimeError, match=f"{phase} of batch 1"):
+                futs[i].result(timeout=10)
+        assert [futs[i].result(timeout=10) for i in (0, 1, 4, 5)] == [
+            0, 10, 40, 50]
+        assert b.submit(6).result(timeout=10) == 60   # serving goes on
+    finally:
+        b.close()
+    status = {r["req_id"]: r["status"] for r in recs}
+    assert [status[i] for i in range(1, 8)] == [
+        "ok", "ok", "error", "error", "ok", "ok", "ok"]
+    assert b.n_served == 5
+
+
+def test_batcher_close_drains_in_flight_batches():
+    runner = _TwoPhase()
+    b, _ = _batcher(runner)
+    futs = [b.submit(i) for i in range(5)]
+    assert runner.wait_dispatched(2) == 2
+    threading.Timer(0.1, runner.release).start()
+    b.close(timeout_s=30)
+    assert not b._worker.is_alive() and not b._collector.is_alive()
+    assert all(f.done() for f in futs)
+    assert [f.result() for f in futs] == [i * 10 for i in range(5)]
+    assert runner.sizes == [2, 2, 1]
+
+
 # ------------------------------------------- batched execution bit-exactness
 def test_session_batched_run_bit_exact_vs_per_request(toy_compiled):
     g, qm, s = toy_compiled
@@ -188,6 +357,33 @@ def test_pallas_backend_batch_dim(rng):
     for i in range(3):
         one = ref(xb[i:i + 1])
         assert np.array_equal(one["p"], got["p"][i:i + 1])
+
+
+def test_executor_dispatch_is_call_without_the_fetch(toy_compiled):
+    """``dispatch`` leaves the outputs on the device; calling the executor
+    on the ``Dispatched`` batch fetches them without a second run; a plain
+    call is both; all run the one jitted program (one compile)."""
+    import jax
+
+    from repro.obs.metrics import REGISTRY
+
+    g, qm, s = toy_compiled
+    ex = Int8Executor(g, qm, strategy=s, backend="ref")
+    x = np.random.default_rng(5).integers(
+        -128, 128, g.shape("data")).astype(np.int8)
+    calls = REGISTRY.counter("executor.calls")
+    before = calls.value
+    out = ex.dispatch(x)
+    assert all(isinstance(v, jax.Array) for v in out.values())
+    fetched = ex(executor.Dispatched(x, out))
+    assert calls.value == before + 1
+    host = ex(x)
+    assert calls.value == before + 2
+    assert set(host) == set(out) == set(fetched)
+    for k in out:
+        assert isinstance(host[k], np.ndarray)
+        assert np.array_equal(fetched[k], host[k]), k
+    assert ex._fn._cache_size() == 1
 
 
 # ------------------------------------------------------- input validation
@@ -342,6 +538,43 @@ def test_server_serves_bit_exact_with_batching(toy_compiled):
             assert np.array_equal(ref[k], got[k]), k
 
 
+def _submit_concurrently(server, reqs, n_threads=4):
+    """Submit ``reqs`` split over ``n_threads`` threads; futures in order."""
+    futs = [None] * len(reqs)
+
+    def client(k):
+        for i in range(k, len(reqs), n_threads):
+            futs[i] = server.submit(reqs[i])
+
+    threads = [threading.Thread(target=client, args=(k,))
+               for k in range(n_threads)]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join(timeout=60)
+        assert not t.is_alive()
+    return futs
+
+
+def test_server_bit_exact_under_concurrent_submitters(toy_compiled):
+    g, qm, s = toy_compiled
+    sess = Session(g, s, ZU2, qm, backend="ref", cache=asm.PlanCache())
+    rng = np.random.default_rng(11)
+    reqs = [rng.integers(-128, 128, g.shape("data")).astype(np.int8)
+            for _ in range(24)]
+    with sess.serve(max_batch=4, allowed_sizes=[4],
+                    max_latency_s=0.002) as server:
+        outs = [f.result(timeout=300)
+                for f in _submit_concurrently(server, reqs)]
+        stats = server.stats()
+    assert stats["n_served"] == 24
+    oracle = Int8Executor(g, qm, strategy=None, backend="ref")
+    for x, got in zip(reqs, outs):
+        ref = oracle(x)
+        for k in sess.outputs:
+            assert np.array_equal(ref[k], got[k]), k
+
+
 # ------------------------------------------------- pin_input planner mode
 def test_pin_input_removes_input_recycling_and_guards():
     """With pin_input the network input's DDR region leaves the reuse pool:
@@ -423,6 +656,42 @@ def test_server_without_slo_keeps_static_cap(toy_compiled):
         stats = server.stats()
     assert stats["effective_max_batch"] == 8
     assert stats["slo_shrinks"] == 0 and stats["slo_grows"] == 0
+
+
+def test_server_slo_resizes_under_collector_appends(toy_compiled):
+    """The SLO controller reads the latency windows on the worker while the
+    collector appends to them, and ``stats()`` reads them from a third
+    thread: no read may fail."""
+    import sys
+
+    g, qm, s = toy_compiled
+    sess = Session(g, s, ZU2, qm, backend="ref", cache=asm.PlanCache())
+    x = np.zeros(tuple(g.shape("data")), np.int8)
+    errors, stop = [], threading.Event()
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    try:
+        with _slo_server(sess, target_p99_ms=0.0) as server:
+            def poll():
+                while not stop.is_set():
+                    try:
+                        server.stats()
+                    except Exception as e:  # noqa: BLE001 -- the finding
+                        errors.append(e)
+
+            poller = threading.Thread(target=poll)
+            poller.start()
+            outs = [f.result(timeout=300)
+                    for f in _submit_concurrently(server, [x] * 64)]
+            stop.set()
+            poller.join(timeout=60)
+            stats = server.stats()
+    finally:
+        stop.set()
+        sys.setswitchinterval(old)
+    assert not poller.is_alive() and errors == []
+    assert len(outs) == 64 and stats["n_served"] == 64
+    assert stats["slo_shrinks"] >= 1
 
 
 def test_batcher_set_max_batch_validates():
