@@ -313,6 +313,8 @@ class Int8Executor:
             self._placed_shapes.add(x.shape)
             for v in out.values():
                 self.devices_seen.update(v.devices())
+            if self.program is not None:
+                self._export_program_stats(x.shape[0])
         REGISTRY.counter("executor.calls").inc()
         if self.program is not None:
             # the jitted program dispatches every item per call; meta carries
@@ -330,6 +332,22 @@ class Int8Executor:
                 REGISTRY.counter("executor.fallback",
                                  {"reason": reason}).inc(n)
         return out
+
+    def _export_program_stats(self, batch: int) -> None:
+        """Gauges of what the program's fused launches occupy and execute
+        (``lower.program_stats``), set on the first dispatch of each batch
+        size: the largest launch's VMEM bytes, conv MACs per image as
+        executed and as the model has them, and the weight bytes one batch
+        pulls from HBM, labelled by the batch size."""
+        from repro.core.lower import program_stats
+        from repro.obs.metrics import REGISTRY
+
+        st = program_stats(self.g, self.program, batch)
+        for name in ("kernel_vmem_bytes_max", "conv_macs_executed",
+                     "conv_macs_model"):
+            REGISTRY.gauge(f"executor.{name}").set(st[name])
+        REGISTRY.gauge("executor.weight_bytes_fetched",
+                       {"batch": batch}).set(st["weight_bytes_fetched"])
 
     def _fallback_reasons(self) -> dict:
         """reason -> launches-per-call, computed once from the program."""

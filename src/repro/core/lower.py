@@ -261,6 +261,55 @@ def lower_horizontal(g: XGraph, qm, members: list,
     return items
 
 
+# ------------------------------------------------------------- launch costs
+def launch_operands(g: XGraph, launch: FusedLaunch) -> tuple:
+    """(input (H, W, C) per image, weight panel shapes, side (H, W, C)s) of
+    a launch, from the graph: what ``ops.launch_blocks`` and
+    ``ops.launch_macs`` take."""
+    in_hwc = tuple(g.shape(launch.in_name)[1:])
+    if launch.kind == "horizontal":
+        kh, kw = launch.kernel
+        oc = sum(oc_m for _, oc_m, _, _ in launch.members)
+        return in_hwc, [(kh, kw, in_hwc[2], oc)], []
+    if launch.fc_reshape:
+        in_hwc = (1, 1, math.prod(in_hwc))
+    weights = []
+    for st in launch.stages:
+        if st[0] == "conv":
+            ic = g.shape(g.nodes[st[1]].inputs[0])[3]
+            if launch.fc_reshape:
+                ic = in_hwc[2]
+            weights.append((st[2], st[3], ic, g.shape(st[1])[3]))
+    return in_hwc, weights, [tuple(g.shape(s)[1:]) for s in launch.sides]
+
+
+def launch_vmem_bytes(g: XGraph, launch: FusedLaunch) -> int:
+    """VMEM the kernel allocates for ``launch`` (``ops.launch_blocks``)."""
+    from repro.kernels.conv_fused import ops
+
+    return ops.launch_blocks(launch, *launch_operands(g, launch)).vmem_bytes()
+
+
+def program_stats(g: XGraph, program: "GroupProgram", batch: int) -> dict:
+    """What the fused launches of ``program`` occupy and do, from the
+    kernel's own blocks and geometry: the largest launch's VMEM bytes, conv
+    MACs per image as executed and as the model has them, and the weight and
+    bias bytes pulled from HBM for one batch of ``batch``."""
+    from repro.kernels.conv_fused import ops
+
+    vmem = executed = model = fetched = 0
+    for launch in program.launches():
+        operands = launch_operands(g, launch)
+        blocks = ops.launch_blocks(launch, *operands, batch=batch)
+        vmem = max(vmem, blocks.vmem_bytes())
+        fetched += blocks.param_bytes_fetched()
+        ex, mo = ops.launch_macs(launch, *operands[:2])
+        executed += ex
+        model += mo
+    return {"kernel_vmem_bytes_max": vmem, "conv_macs_executed": executed,
+            "conv_macs_model": model, "weight_bytes_fetched": fetched}
+
+
 # ---------------------------------------------------------- strategy lowering
 def lower_strategy(g: XGraph, strategy, qm=None) -> GroupProgram:
     """Lower a whole strategy (or per-node naive execution when ``strategy``

@@ -14,7 +14,9 @@ Concretely here:
   2. each chain is optimally partitioned into fused segments by Floyd over
      cut-points — edge (i, j) exists iff ops[i+1..j] is a valid fused group
      (consecutive pairs match a kernel-fusion template AND the tiling solver
-     proves fusion condition 1), weighted by the cost evaluator;
+     proves fusion condition 1 AND, planning for the TPU, the chain kernel's
+     own VMEM footprint fits the scoped-VMEM limit), weighted by the cost
+     evaluator;
   3. at each eltwise merge barrier we enumerate absorbing the eltwise into
      each incoming branch vs. standalone, and keep the cheapest;
   4. at each fork barrier whose consumers are convolutions we enumerate
@@ -42,13 +44,15 @@ HORIZONTAL_OK = templates.CONVS | templates.POOLS
 # this strategy and not another, at a few KB per model.
 TRACE_MAX_CHAINS = 64
 TRACE_MAX_ALTERNATIVES = 8
-TRACE_MAX_REJECT_EXAMPLES = 4
+TRACE_MAX_REJECT_EXAMPLES = 4          # per rejection reason
 
 # Machine-readable rejection vocabulary (mirrors lower.FALLBACK_REASONS in
 # spirit): every candidate segment the search discards carries one of these.
 REJECT_REASONS = frozenset({
     "no_fusion_template",   # a consecutive pair matches no kernel template
     "infeasible_tiling",    # tiling solver failed fusion condition 1 (Eq. 6)
+    "exceeds_kernel_vmem",  # the chain kernel's blocks and scratch overflow
+                            # the scoped-VMEM limit (TPU only)
 })
 
 
@@ -102,14 +106,17 @@ def _segment_valid(g: XGraph, ops: list[str], pairs: set) -> bool:
 
 def partition_chain(g: XGraph, chain: list[str], pairs: set, evaluator, *,
                     collect: dict | None = None,
-                    seg_costs: dict | None = None) -> tuple[list[list[str]], float]:
+                    seg_costs: dict | None = None,
+                    kernel_fits=None) -> tuple[list[list[str]], float]:
     """Optimal partition of one chain into fused segments via Floyd (paper's
     choice; O(m^3) with m = chain length, m is small for real CNNs).
 
     ``collect``/``seg_costs`` are optional trace sinks: direct per-segment
     evaluator costs must be captured here at matrix-fill time, because the
     Floyd relaxation below overwrites ``cost[i][j]`` with multi-segment path
-    costs and the candidate scores are unrecoverable afterwards."""
+    costs and the candidate scores are unrecoverable afterwards.
+    ``kernel_fits(seg)`` (see :func:`kernel_vmem_check`), where given, must
+    also hold for a segment the evaluator finds feasible."""
     m = len(chain)
     big = INFEASIBLE
     cost = [[big] * (m + 1) for _ in range(m + 1)]
@@ -124,6 +131,11 @@ def partition_chain(g: XGraph, chain: list[str], pairs: set, evaluator, *,
                     collect["rejected"].append((seg, "no_fusion_template"))
                 continue
             c = evaluator(seg)
+            if math.isfinite(c) and kernel_fits is not None \
+                    and not kernel_fits(seg):
+                if collect is not None:
+                    collect["rejected"].append((seg, "exceeds_kernel_vmem"))
+                continue
             if math.isfinite(c):
                 cost[i][j] = c
                 n_feasible += 1
@@ -165,6 +177,39 @@ def partition_chain(g: XGraph, chain: list[str], pairs: set, evaluator, *,
     return segs, cost[0][m]
 
 
+def kernel_vmem_check(g: XGraph, dev: DeviceModel):
+    """``seg -> bool`` that admits a candidate segment only if the chain
+    kernel's VMEM footprint at the tile it runs (``lower.launch_vmem_bytes``,
+    no searched tile) fits the scoped-VMEM limit it is compiled with, or
+    None where ``dev`` is not the TPU the kernel targets.  A segment that
+    lowers to no fused launch is not the kernel's to judge.  The footprints
+    stay on the check (``.footprint``, ``.limit``); each rejected segment
+    counts once in ``pathsearch.segments_rejected{reason=exceeds_kernel_vmem}``.
+    """
+    from repro.hw.device import TPU_V5E, V5E_VMEM_BYTES
+
+    if dev.name != TPU_V5E.name:
+        return None
+    from repro.core import lower
+    from repro.obs.metrics import REGISTRY
+
+    footprint: dict[tuple, int] = {}
+
+    def fits(seg) -> bool:
+        key = tuple(seg)
+        if key not in footprint:
+            item = lower.lower_group(g, None, list(seg))
+            footprint[key] = (lower.launch_vmem_bytes(g, item)
+                              if isinstance(item, lower.FusedLaunch) else 0)
+            if footprint[key] > V5E_VMEM_BYTES:
+                REGISTRY.counter("pathsearch.segments_rejected",
+                                 {"reason": "exceeds_kernel_vmem"}).inc()
+        return footprint[key] <= V5E_VMEM_BYTES
+
+    fits.footprint, fits.limit = footprint, V5E_VMEM_BYTES
+    return fits
+
+
 # ------------------------------------------------------------ the search
 def search(g: XGraph, dev: DeviceModel, evaluator=None,
            device_of=None, enable_horizontal: bool = True,
@@ -183,6 +228,7 @@ def _search(g: XGraph, dev: DeviceModel, evaluator=None,
                  if n.op != "input" and (device_of is None or device_of(n.name) == "acc")}
     matches = isomorphism.find_all(g, templates.KERNEL_TEMPLATES)
     pairs = templates.pairwise_fusable(matches)
+    fits = kernel_vmem_check(g, dev)
 
     chains = chains_of(g, plannable)
     chain_of_node = {}
@@ -210,7 +256,8 @@ def _search(g: XGraph, dev: DeviceModel, evaluator=None,
     for idx, ch in enumerate(chains):
         collect = _collector()
         solved[idx] = partition_chain(g, ch, pairs, evaluator,
-                                      collect=collect, seg_costs=seg_costs)
+                                      collect=collect, seg_costs=seg_costs,
+                                      kernel_fits=fits)
         if collect is not None:
             collect["nodes"] = list(ch)
             collect["chosen"] = [list(s) for s in solved[idx][0]]
@@ -234,13 +281,15 @@ def _search(g: XGraph, dev: DeviceModel, evaluator=None,
             # candidate: chain' = pch + [head], this chain loses its head
             try:
                 new_p, cost_p = partition_chain(g, pch + [head], pairs,
-                                                evaluator, seg_costs=seg_costs)
+                                                evaluator, seg_costs=seg_costs,
+                                                kernel_fits=fits)
             except RuntimeError:
                 continue
             rest = ch[1:]
             if rest:
                 new_c, cost_c = partition_chain(g, rest, pairs, evaluator,
-                                                seg_costs=seg_costs)
+                                                seg_costs=seg_costs,
+                                                kernel_fits=fits)
             else:
                 new_c, cost_c = [], 0.0
             old = solved[pidx][1] + solved[idx][1]
@@ -301,7 +350,8 @@ def _search(g: XGraph, dev: DeviceModel, evaluator=None,
                 if rest:
                     try:
                         tg, tc = partition_chain(g, rest, pairs, evaluator,
-                                                 seg_costs=seg_costs)
+                                                 seg_costs=seg_costs,
+                                                 kernel_fits=fits)
                     except RuntimeError:
                         ok = False
                         break
@@ -341,7 +391,8 @@ def _search(g: XGraph, dev: DeviceModel, evaluator=None,
     if trace:
         strategy.meta["search_trace"] = _build_trace(
             g, dev, evaluator, matches, pairs, chains, chain_traces,
-            eltwise_trace, horizontal_trace, seg_costs, h_cost_of, strategy)
+            eltwise_trace, horizontal_trace, seg_costs, h_cost_of, strategy,
+            fits)
     # provenance: which cost oracle picked this strategy.  A profile-guided
     # evaluator (tune.CalibratedEvaluator) carries its DeviceProfile; the hash
     # flows into the compiled artifact so a loaded plan knows what it was
@@ -422,7 +473,7 @@ def naive(g: XGraph, dev: DeviceModel, evaluator=None, device_of=None) -> Strate
 # ----------------------------------------------------------------- trace
 def _build_trace(g, dev, evaluator, matches, pairs, chains, chain_traces,
                  eltwise_trace, horizontal_trace, seg_costs, h_cost_of,
-                 strategy) -> dict:
+                 strategy, fits=None) -> dict:
     """Assemble the bounded, JSON-native search trace for strategy.meta.
 
     The trace answers three questions the final Strategy alone cannot: which
@@ -447,8 +498,12 @@ def _build_trace(g, dev, evaluator, matches, pairs, chains, chain_traces,
         examples: list[dict] = []
         for seg, why in ct["rejected"]:
             reasons[why] = reasons.get(why, 0) + 1
-            if len(examples) < TRACE_MAX_REJECT_EXAMPLES:
-                examples.append({"nodes": list(seg), "reason": why})
+            if reasons[why] <= TRACE_MAX_REJECT_EXAMPLES:
+                ex = {"nodes": list(seg), "reason": why}
+                if why == "exceeds_kernel_vmem":
+                    ex["vmem_bytes"] = fits.footprint[tuple(seg)]
+                    ex["limit_bytes"] = fits.limit
+                examples.append(ex)
         chain_records.append({
             "nodes": list(ct["nodes"]),
             "m": ct.get("m", len(ct["nodes"])),

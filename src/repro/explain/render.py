@@ -85,11 +85,18 @@ def render_report(rep: dict, *, drift: list | None = None,
                          f"cost {_us(alt.get('cost_s'))}")
                 rows += 1
         for ch in search.get("chains", []):
-            for ex in ch.get("rejected_examples", [])[:2]:
+            shown: dict[str, int] = {}
+            for ex in ch.get("rejected_examples", []):
                 if rows >= max_rows:
                     break
+                shown[ex["reason"]] = shown.get(ex["reason"], 0) + 1
+                if shown[ex["reason"]] > 2:        # two of each reason
+                    continue
+                vmem = (f" ({ex['vmem_bytes'] / 2**20:.1f} MiB of VMEM, "
+                        f"limit {ex['limit_bytes'] / 2**20:.0f} MiB)"
+                        if "vmem_bytes" in ex else "")
                 L.append(f"  [rejected] {'|'.join(ex['nodes'])}  "
-                         f"reason={ex['reason']}")
+                         f"reason={ex['reason']}{vmem}")
                 rows += 1
         for ew in search.get("eltwise_absorb", []):
             word = (f"absorbed into {ew['into']}" if ew.get("absorbed")

@@ -45,7 +45,9 @@ sibling convs over OC-stacked weights with *per-channel* shift/ReLU vectors.
 """
 from __future__ import annotations
 
+import dataclasses
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -325,13 +327,148 @@ def _chain_kernel(*refs, chain, geom, chans):
 def _compiler_params(interpret: bool):
     """Scoped-VMEM limit of one launch: the budget ``hw.TPU_V5E`` plans tiles
     under (Mosaic's 16 MiB default is too small for the stem's whole-image
-    block, ~15 MiB lane-padded and double-buffered)."""
+    block, ~15 MiB lane-padded and double-buffered).  The fusion search
+    admits a chain only if its ``LaunchBlocks.vmem_bytes()`` fits it."""
     return (None if interpret
             else pltpu.CompilerParams(vmem_limit_bytes=V5E_VMEM_BYTES))
 
 
 def _vmem(shape):
     return pltpu.VMEM(tuple(int(d) for d in shape), jnp.int32)
+
+
+# ------------------------------------------------------------ VMEM footprint
+SUBLANES = {1: 32, 4: 8}      # rows of one (sublane, lane) tile, by itemsize
+
+
+def padded_bytes(shape, itemsize: int) -> int:
+    """Bytes a VMEM buffer of ``shape`` occupies: Mosaic lays out its two
+    minor dims in tiles of 128 lanes by 8 rows (int32) or 32 rows (int8)."""
+    *lead, sub, lane = shape
+    rows = SUBLANES[itemsize]
+    return (math.prod(lead) * -(-sub // rows) * rows
+            * -(-lane // LANES) * LANES * itemsize)
+
+
+@dataclasses.dataclass(frozen=True)
+class Block:
+    """One pipelined operand of a launch: its block shape, element size, and
+    for each block dim the grid axis its block index follows (None: 0)."""
+    shape: tuple
+    itemsize: int
+    follows: tuple
+
+    def index_map(self):
+        follows = self.follows
+        return lambda *ids: tuple(0 if a is None else ids[a] for a in follows)
+
+    def fetches(self, grid) -> int:
+        """Copies of this block from HBM over the grid: the pipeline fetches
+        it again whenever its block index changes between consecutive
+        (row-major) grid steps."""
+        moving = [a for a in self.follows if a is not None and grid[a] > 1]
+        return math.prod(grid[:max(moving) + 1]) if moving else 1
+
+
+@dataclasses.dataclass(frozen=True)
+class LaunchBlocks:
+    """Grid, pipelined blocks (operands in argument order, then the output)
+    and int32 scratch of one launch: what it allocates in VMEM and what it
+    pulls from HBM."""
+    grid: tuple
+    inputs: tuple
+    out: Block
+    scratch: tuple
+    params: tuple              # indices into ``inputs``: weights and biases
+    chans: tuple = ()          # chain: channels of each stage's input buffer
+
+    def vmem_bytes(self) -> int:
+        """Every pipelined block double-buffered, plus the scratch."""
+        piped = sum(padded_bytes(b.shape, b.itemsize)
+                    for b in self.inputs + (self.out,))
+        return 2 * piped + sum(padded_bytes(s, 4) for s in self.scratch)
+
+    def param_bytes_fetched(self) -> int:
+        """Weight and bias bytes the grid pulls from HBM."""
+        return sum(math.prod(b.shape) * b.itemsize * b.fetches(self.grid)
+                   for b in (self.inputs[i] for i in self.params))
+
+
+def chain_blocks(chain, geom, x_shape, weight_shapes, side_shapes, *, toc,
+                 oc) -> LaunchBlocks:
+    """Blocks of a chain launch over the pre-padded input ``x_shape``, one
+    (KH, KW, IC, OC) weight panel per conv stage and one pre-padded side per
+    elt stage.  The final conv's weights, bias and downstream sides ride the
+    OC-tile axis; every other conv's panel is resident whole."""
+    n, hp, wp, c = x_shape
+    conv_idx = [i for i, st in enumerate(chain) if st[0] == "conv"]
+    last_conv = conv_idx[-1] if conv_idx else -1
+    inputs = [Block((1, hp, wp, c), 1, (0, None, None, None))]
+    for (kh, kw, ic, oc_i), ci in zip(weight_shapes, conv_idx):
+        if ci == last_conv:
+            inputs += [Block((kh, kw, ic, toc), 1, (None, None, None, 3)),
+                       Block((1, toc), 4, (None, 3))]
+        else:
+            inputs += [Block((kh, kw, ic, oc_i), 1, (None,) * 4),
+                       Block((1, oc_i), 4, (None, None))]
+    params = tuple(range(1, len(inputs)))
+    elt_idx = [i for i, st in enumerate(chain) if st[0] == "elt"]
+    for ei, (_, shp, swp, sc) in zip(elt_idx, side_shapes):
+        if ei > last_conv:   # rides the TOC slice of the final conv
+            inputs.append(Block((1, shp, swp, toc), 1, (0, None, None, 3)))
+        else:
+            inputs.append(Block((1, shp, swp, sc), 1, (0, None, None, None)))
+
+    # one int32 buffer per stage input: the staged image window, then every
+    # intermediate map (channels: a conv's panel width — TOC for the final
+    # conv — else the channels it was fed)
+    chans, wi = [c], 0
+    for i, st in enumerate(chain[:-1]):
+        ch = chans[-1]
+        if st[0] == "conv":
+            ch = toc if i == last_conv else int(weight_shapes[wi][-1])
+            wi += 1
+        chans.append(ch)
+    extents = [(geom["in_rows"], geom["in_cols"])] + [
+        (geom["rows"][i], geom["cols"][i]) for i in range(len(chain) - 1)]
+    return LaunchBlocks(
+        grid=(n, geom["n_h"], geom["n_w"], oc // toc), inputs=tuple(inputs),
+        out=Block((1, geom["th"], geom["tw"], toc), 1, (0, 1, 2, 3)),
+        scratch=tuple(_buf_shape(r, cc, ch)
+                      for (r, cc), ch in zip(extents, chans)),
+        params=params, chans=tuple(chans))
+
+
+def horizontal_blocks(x_shape, w_shape, *, stride, th, tw, toc, oh, ow
+                      ) -> LaunchBlocks:
+    """Blocks of a horizontal launch: the pre-padded input, OC-stacked
+    weights and the bias, shift and ReLU vectors, all but the input riding
+    the OC-tile axis."""
+    n, hp, wp, ic = x_shape
+    kh, kw, _, oc = w_shape
+    sh, sw = stride
+    vec = Block((1, toc), 4, (None, 3))
+    return LaunchBlocks(
+        grid=(n, -(-oh // th), -(-ow // tw), oc // toc),
+        inputs=(Block((1, hp, wp, ic), 1, (0, None, None, None)),
+                Block((kh, kw, ic, toc), 1, (None, None, None, 3)),
+                vec, vec, vec),
+        out=Block((1, th, tw, toc), 1, (0, 1, 2, 3)),
+        scratch=(_buf_shape((th - 1) * sh + kh, (tw - 1) * sw + kw, ic),),
+        params=(1, 2))
+
+
+def _pallas(kern, blocks: LaunchBlocks, out_shape, interpret: bool):
+    return pl.pallas_call(
+        kern,
+        grid=blocks.grid,
+        in_specs=[pl.BlockSpec(b.shape, b.index_map()) for b in blocks.inputs],
+        out_specs=pl.BlockSpec(blocks.out.shape, blocks.out.index_map()),
+        out_shape=jax.ShapeDtypeStruct(out_shape, jnp.int8),
+        scratch_shapes=[_vmem(s) for s in blocks.scratch],
+        compiler_params=_compiler_params(interpret),
+        interpret=interpret,
+    )
 
 
 def fused_chain_pallas(x_pad, weights, biases, sides, *, chain, th, toc,
@@ -349,67 +486,18 @@ def fused_chain_pallas(x_pad, weights, biases, sides, *, chain, th, toc,
              (oh, ow) here — intermediate masking keeps every valid position
              bit-exact, the sliced-off slack is never read.
     """
-    n, hp, wp, c = x_pad.shape
+    n = x_pad.shape[0]
     geom = chain_geometry(chain, th, oh, ow, tw)
-    tw = geom["tw"]
-    conv_idx = [i for i, st in enumerate(chain) if st[0] == "conv"]
-    last_conv = conv_idx[-1] if conv_idx else -1
-
-    grid = (n, geom["n_h"], geom["n_w"], oc // toc)
-    in_specs = [pl.BlockSpec((1, hp, wp, c), lambda i, j, jw, k: (i, 0, 0, 0))]
-    args = [x_pad]
-    for w, b, ci in zip(weights, biases, conv_idx):
-        kh, kw, ic, oc_i = w.shape
-        if ci == last_conv:
-            in_specs.append(pl.BlockSpec((kh, kw, ic, toc),
-                                         lambda i, j, jw, k: (0, 0, 0, k)))
-            in_specs.append(pl.BlockSpec((1, toc), lambda i, j, jw, k: (0, k)))
-        else:
-            in_specs.append(pl.BlockSpec((kh, kw, ic, oc_i),
-                                         lambda i, j, jw, k: (0, 0, 0, 0)))
-            in_specs.append(pl.BlockSpec((1, oc_i),
-                                         lambda i, j, jw, k: (0, 0)))
-        args.extend([w, b])
-    elt_idx = [i for i, st in enumerate(chain) if st[0] == "elt"]
-    for ei, s in zip(elt_idx, sides):
-        sn, shp, swp, sc = s.shape
-        if ei > last_conv:   # rides the TOC slice of the final conv
-            in_specs.append(pl.BlockSpec((1, shp, swp, toc),
-                                         lambda i, j, jw, k: (i, 0, 0, k)))
-        else:
-            in_specs.append(pl.BlockSpec((1, shp, swp, sc),
-                                         lambda i, j, jw, k: (i, 0, 0, 0)))
-        args.append(s)
-
-    # one int32 buffer per stage input: the staged image window, then every
-    # intermediate map (channels: a conv's panel width — TOC for the final
-    # conv — else the channels it was fed)
-    chans, wi = [c], 0
-    for i, st in enumerate(chain[:-1]):
-        ch = chans[-1]
-        if st[0] == "conv":
-            ch = toc if i == last_conv else int(weights[wi].shape[-1])
-            wi += 1
-        chans.append(ch)
-    extents = [(geom["in_rows"], geom["in_cols"])] + [
-        (geom["rows"][i], geom["cols"][i]) for i in range(len(chain) - 1)]
-    bufs = [_vmem(_buf_shape(r, cc, ch)) for (r, cc), ch in zip(extents, chans)]
-
+    blocks = chain_blocks(chain, geom, x_pad.shape, [w.shape for w in weights],
+                          [s.shape for s in sides], toc=toc, oc=oc)
     kern = functools.partial(_chain_kernel, chain=chain, geom=geom,
-                             chans=tuple(chans))
-    fn = pl.pallas_call(
-        kern,
-        grid=grid,
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, th, tw, toc),
-                               lambda i, j, jw, k: (i, j, jw, k)),
-        out_shape=jax.ShapeDtypeStruct(
-            (n, geom["n_h"] * th, geom["n_w"] * tw, oc), jnp.int8),
-        scratch_shapes=bufs,
-        compiler_params=_compiler_params(interpret),
-        interpret=interpret,
-    )
-    return fn(*args)[:, :oh, :ow]
+                             chans=blocks.chans)
+    fn = _pallas(kern, blocks, (n, geom["n_h"] * th, geom["n_w"] * geom["tw"],
+                                oc), interpret)
+    args = [x_pad]
+    for w, b in zip(weights, biases):
+        args.extend([w, b])
+    return fn(*args, *sides)[:, :oh, :ow]
 
 
 # ------------------------------------------------------ horizontal (stacked)
@@ -445,28 +533,13 @@ def fused_horizontal_pallas(x_pad, w, b, shift_vec, relu_vec, *, stride,
     bottom/right tiles; their zero-fed slack positions are sliced off here).
     """
     n, hp, wp, ic = x_pad.shape
-    kh, kw, _, oc = w.shape
-    sh, sw = stride
+    kh, kw = w.shape[:2]
     tw = ow if tw is None else tw
-    n_h = -(-oh // th)
-    n_w = -(-ow // tw)
-    kern = functools.partial(_horizontal_kernel, kh=kh, kw=kw, sh=sh, sw=sw,
-                             th=th, tw=tw, n_w=n_w, ic=ic)
-    vec = pl.BlockSpec((1, toc), lambda i, j, jw, k: (0, k))
-    fn = pl.pallas_call(
-        kern,
-        grid=(n, n_h, n_w, oc // toc),
-        in_specs=[
-            pl.BlockSpec((1, hp, wp, ic), lambda i, j, jw, k: (i, 0, 0, 0)),
-            pl.BlockSpec((kh, kw, ic, toc), lambda i, j, jw, k: (0, 0, 0, k)),
-            vec, vec, vec,
-        ],
-        out_specs=pl.BlockSpec((1, th, tw, toc),
-                               lambda i, j, jw, k: (i, j, jw, k)),
-        out_shape=jax.ShapeDtypeStruct((n, n_h * th, n_w * tw, oc), jnp.int8),
-        scratch_shapes=[_vmem(_buf_shape((th - 1) * sh + kh,
-                                         (tw - 1) * sw + kw, ic))],
-        compiler_params=_compiler_params(interpret),
-        interpret=interpret,
-    )
+    blocks = horizontal_blocks(x_pad.shape, w.shape, stride=stride, th=th,
+                               tw=tw, toc=toc, oh=oh, ow=ow)
+    _, n_h, n_w, _ = blocks.grid
+    kern = functools.partial(_horizontal_kernel, kh=kh, kw=kw, sh=stride[0],
+                             sw=stride[1], th=th, tw=tw, n_w=n_w, ic=ic)
+    fn = _pallas(kern, blocks, (n, n_h * th, n_w * tw, w.shape[-1]),
+                 interpret)
     return fn(x_pad, w, b, shift_vec, relu_vec)[:, :oh, :ow]

@@ -8,6 +8,9 @@
 ``fused_conv_block``— legacy single-conv(+tail) wrapper (kernel tests,
                       micro-benchmarks).
 ``supports``        — static support predicate of the chain kernel.
+``launch_blocks``   — the grid and blocks one launch runs with: its VMEM
+                      footprint and the weight bytes it pulls from HBM.
+``launch_macs``     — the conv MACs one launch executes and the model's.
 """
 from __future__ import annotations
 
@@ -19,7 +22,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.kernels.conv_fused.conv_fused import (
-    I8_MIN, chain_geometry, fused_chain_pallas, fused_horizontal_pallas)
+    I8_MIN, LaunchBlocks, _true_hw, chain_blocks, chain_geometry,
+    fused_chain_pallas, fused_horizontal_pallas, horizontal_blocks)
 
 
 def _tile_rows(oh: int, pref=(8, 4, 2, 1)) -> int:
@@ -90,12 +94,26 @@ def supports(*, depthwise=False, **_ignored) -> bool:
     return not depthwise
 
 
+def _padded_hw(h: int, w: int, top: int, left: int, h_req: int,
+               w_req: int) -> tuple:
+    """Extents of an (h, w) map padded by ``top``/``left`` and out to at
+    least (h_req, w_req)."""
+    return max(h_req, top + h), max(w_req, left + w)
+
+
 def _pad_to(x, top: int, left: int, h_req: int, w_req: int, fill: int):
     n, h, w, c = x.shape
-    bottom = max(0, h_req - top - h)
-    right = max(0, w_req - left - w)
-    return jnp.pad(x, ((0, 0), (top, bottom), (left, right), (0, 0)),
-                   constant_values=np.int8(fill))
+    hp, wp = _padded_hw(h, w, top, left, h_req, w_req)
+    return jnp.pad(x, ((0, 0), (top, hp - top - h), (left, wp - left - w),
+                       (0, 0)), constant_values=np.int8(fill))
+
+
+def _horizontal_pad(kernel, stride, pad, oh: int, ow: int, th: int,
+                    tw: int) -> tuple:
+    """(top, left, h_req, w_req) of a horizontal launch's padded input."""
+    (kh, kw), (sh, sw) = kernel, stride
+    return (pad[0], pad[1], (-(-oh // th) * th - 1) * sh + kh,
+            (-(-ow // tw) * tw - 1) * sw + kw)
 
 
 @partial(jax.jit, static_argnames=("chain", "oh", "ow", "oc", "interpret",
@@ -119,18 +137,80 @@ def _run_chain(x, weights, biases, sides, *, chain, oh, ow, oc, interpret,
                                    "tile"))
 def _run_horizontal(x, w, b, shift_vec, relu_vec, *, stride, pad, oh, ow,
                     interpret, tile=()):
-    kh, kw = w.shape[:2]
-    sh, sw = stride
     th, tw, toc = _resolve_tile(tile, oh, ow, int(w.shape[-1]), 1)
-    n_h = -(-oh // th)
-    n_w = -(-ow // tw)
-    xp = _pad_to(x, pad[0], pad[1], (n_h * th - 1) * sh + kh,
-                 (n_w * tw - 1) * sw + kw, 0)
+    xp = _pad_to(x, *_horizontal_pad(w.shape[:2], stride, pad, oh, ow, th,
+                                     tw), 0)
     b, shift_vec, relu_vec = (v.reshape(1, -1)
                               for v in (b, shift_vec, relu_vec))
     return fused_horizontal_pallas(xp, w, b, shift_vec, relu_vec,
                                    stride=stride, th=th, tw=tw, toc=toc,
                                    oh=oh, ow=ow, interpret=interpret)
+
+
+# ------------------------------------------------------- static launch costs
+def _chain_tile(chain, weight_shapes, in_c: int, tile) -> tuple:
+    """(th, tw, toc, oc, geometry) a chain launch runs with."""
+    oh, ow = _true_hw(chain[-1])
+    oc = int(weight_shapes[-1][-1]) if weight_shapes else in_c
+    th, tw, toc = _resolve_tile(tuple(tile), oh, ow, oc, len(weight_shapes))
+    return th, tw, toc, oc, chain_geometry(chain, th, oh, ow, tw)
+
+
+def launch_blocks(launch, in_hwc, weight_shapes, side_hwcs=(),
+                  batch: int = 1) -> LaunchBlocks:
+    """The grid and blocks ``launch`` runs with on a batch of ``batch``:
+    the same ``chain_blocks`` / ``horizontal_blocks`` the kernel launches
+    with, over the input padded as ``_run_chain`` / ``_run_horizontal`` pad
+    it.  ``in_hwc`` is the launch's input per image (an fc's flattened to
+    (1, 1, K)), ``weight_shapes`` one (KH, KW, IC, OC) panel per conv stage
+    (a horizontal launch: the one OC-stacked panel), ``side_hwcs`` one per
+    elt stage."""
+    h, w, c = in_hwc
+    if launch.kind == "horizontal":
+        (ws,) = weight_shapes
+        oh, ow = launch.out_hw
+        th, tw, toc = _resolve_tile(tuple(launch.tile), oh, ow, ws[-1], 1)
+        hp, wp = _padded_hw(h, w, *_horizontal_pad(
+            launch.kernel, launch.stride, launch.pad, oh, ow, th, tw))
+        return horizontal_blocks((batch, hp, wp, c), ws,
+                                 stride=tuple(launch.stride), th=th, tw=tw,
+                                 toc=toc, oh=oh, ow=ow)
+    chain = launch.stages
+    _, _, toc, oc, geom = _chain_tile(chain, weight_shapes, c, launch.tile)
+    hp, wp = _padded_hw(h, w, *geom["q_in"], geom["h_req"], geom["w_req"])
+    sides = [(batch,) + _padded_hw(sh, sw, *sg["q"], sg["h_req"],
+                                   sg["w_req"]) + (sc,)
+             for (sh, sw, sc), sg in zip(side_hwcs, geom["sides"])]
+    return chain_blocks(chain, geom, (batch, hp, wp, c), weight_shapes,
+                        sides, toc=toc, oc=oc)
+
+
+def launch_macs(launch, in_hwc, weight_shapes) -> tuple:
+    """(executed, model) conv multiply-accumulates of ``launch`` per image.
+    Executed counts every conv stage at the rows and columns the kernel
+    computes: halo rows and columns staged again for each row and width
+    tile, ragged tiles' slack, and the stages upstream of the final conv
+    once per OC tile.  Model counts the true output extents once."""
+    if launch.kind == "horizontal":
+        (ws,) = weight_shapes
+        kh, kw, ic, oc = ws
+        oh, ow = launch.out_hw
+        th, tw, _ = _resolve_tile(tuple(launch.tile), oh, ow, oc, 1)
+        per_pos = kh * kw * ic * oc
+        return (-(-oh // th) * th * -(-ow // tw) * tw * per_pos,
+                oh * ow * per_pos)
+    chain = launch.stages
+    _, _, toc, oc, geom = _chain_tile(chain, weight_shapes, in_hwc[-1],
+                                      launch.tile)
+    conv_idx = [i for i, st in enumerate(chain) if st[0] == "conv"]
+    steps = geom["n_h"] * geom["n_w"] * (oc // toc)
+    executed = model = 0
+    for i, (kh, kw, ic, oc_i) in zip(conv_idx, weight_shapes):
+        per_pos = kh * kw * ic
+        depth = toc if i == conv_idx[-1] else oc_i
+        executed += steps * geom["rows"][i] * geom["cols"][i] * per_pos * depth
+        model += chain[i][12] * chain[i][13] * per_pos * oc_i
+    return executed, model
 
 
 # ------------------------------------------------------------ executor hook

@@ -1,9 +1,10 @@
 """Ahead-of-time compiles of the fused kernels for a described TPU v5e.
 
-Each case lowers one launch kind of ResNet-50 @224 exactly as the planner
-emits it and compiles it with ``interpret=False`` against one chip of a
-described ``v5e:2x2`` topology: what Mosaic refuses (layouts, strided loads,
-block shapes, VMEM) fails here without a chip.  Nothing runs.
+Each case lowers one launch kind of ResNet-50 @224, or one launch of
+VGG-16 @224, exactly as the planner emits it and compiles it with
+``interpret=False`` against one chip of a described ``v5e:2x2`` topology:
+what Mosaic refuses (layouts, strided loads, block shapes, VMEM) fails here
+without a chip.  Nothing runs.
 """
 import os
 
@@ -47,6 +48,17 @@ def resnet50():
     return g, lower.lower_strategy(g, pathsearch.search(g, TPU_V5E))
 
 
+@pytest.fixture(scope="module")
+def vgg16():
+    """(graph, lowered program) of VGG-16 @224 under the TPU plan."""
+    from repro.cnn import build
+    from repro.core import lower, pathsearch
+    from repro.hw import TPU_V5E
+
+    g = build("vgg16")
+    return g, lower.lower_strategy(g, pathsearch.search(g, TPU_V5E))
+
+
 @pytest.fixture
 def no_persistent_cache():
     """A compile for a described chip is written to the persistent cache but
@@ -70,33 +82,24 @@ def _chain_args(g, launch, sharding, batch=4):
     import jax
     import jax.numpy as jnp
 
+    from repro.core.lower import launch_operands
+
     def spec(shape, dt):
         return jax.ShapeDtypeStruct(tuple(shape), dt, sharding=sharding)
 
-    x = [batch] + list(g.shape(launch.in_name)[1:])
-    if launch.fc_reshape:
-        x = [batch, 1, 1, x[1] * x[2] * x[3]]
-    ws, bs = [], []
-    for st in launch.stages:
-        if st[0] == "conv":
-            node = g.nodes[st[1]]
-            kh, kw = node.attrs.get("kernel", (1, 1))
-            ic = x[3] if launch.fc_reshape else g.shape(node.inputs[0])[3]
-            oc = g.shape(st[1])[3]
-            ws.append(spec((kh, kw, ic, oc), jnp.int8))
-            bs.append(spec((oc,), jnp.int32))
-    sides = tuple(spec([batch] + list(g.shape(s)[1:]), jnp.int8)
-                  for s in launch.sides)
-    oc = ws[-1].shape[-1] if ws else x[3]
-    return spec(x, jnp.int8), tuple(ws), tuple(bs), sides, oc
+    in_hwc, weights, sides = launch_operands(g, launch)
+    ws = tuple(spec(w, jnp.int8) for w in weights)
+    bs = tuple(spec((w[-1],), jnp.int32) for w in weights)
+    sides = tuple(spec((batch,) + s, jnp.int8) for s in sides)
+    oc = weights[-1][-1] if weights else in_hwc[-1]
+    return spec((batch,) + in_hwc, jnp.int8), ws, bs, sides, oc
 
 
-def _compile_launch(g, prog, kind, sharding, tile=None):
+def _compile_launch(g, prog, first, sharding, tile=None, batch=4):
     from repro.kernels.conv_fused import ops
 
-    launch = next(it for it in prog.launches()
-                  if it.nodes[0] == LAUNCHES[kind])
-    x, ws, bs, sides, oc = _chain_args(g, launch, sharding)
+    launch = next(it for it in prog.launches() if it.nodes[0] == first)
+    x, ws, bs, sides, oc = _chain_args(g, launch, sharding, batch)
     oh, ow = launch.out_hw
     tile = tuple(launch.tile) if tile is None else tile
 
@@ -110,7 +113,21 @@ def _compile_launch(g, prog, kind, sharding, tile=None):
 @pytest.mark.parametrize("kind", sorted(LAUNCHES))
 def test_resnet50_launch_compiles_for_v5e(kind, one_chip, resnet50,
                                           no_persistent_cache):
-    compiled = _compile_launch(*resnet50, kind, one_chip)
+    compiled = _compile_launch(*resnet50, LAUNCHES[kind], one_chip)
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("first", ["conv1", "conv3", "pool7", "pool10",
+                                   "pool13", "fc6", "fc7", "fc8"])
+def test_vgg16_launch_compiles_for_v5e(first, one_chip, vgg16,
+                                       no_persistent_cache):
+    """Every launch of the VGG-16 program, at the served batch of 32: the
+    segments the kernel-VMEM check admits compile within the scoped-VMEM
+    limit (the whole 13-conv chain the search chose without it did not)."""
+    g, prog = vgg16
+    assert {la.nodes[0] for la in prog.launches()} == {
+        "conv1", "conv3", "pool7", "pool10", "pool13", "fc6", "fc7", "fc8"}
+    compiled = _compile_launch(g, prog, first, one_chip, batch=32)
     assert "tpu_custom_call" in compiled.as_text()
 
 
@@ -136,7 +153,7 @@ def test_resnet50_tiled_launch_compiles_for_v5e(kind, tile, n_w, n_oc,
     n_conv = sum(1 for st in launch.stages if st[0] == "conv")
     assert _resolve_tile(tile, oh, ow, oc, n_conv) == tile
     assert (-(-ow // tile[1]), oc // tile[2]) == (n_w, n_oc)
-    compiled = _compile_launch(g, prog, kind, one_chip, tile=tile)
+    compiled = _compile_launch(g, prog, LAUNCHES[kind], one_chip, tile=tile)
     assert "tpu_custom_call" in compiled.as_text()
 
 
