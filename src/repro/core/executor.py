@@ -337,14 +337,15 @@ class Int8Executor:
         """Gauges of what the program's fused launches occupy and execute
         (``lower.program_stats``), set on the first dispatch of each batch
         size: the largest launch's VMEM bytes, conv MACs per image as
-        executed and as the model has them, and the weight bytes one batch
-        pulls from HBM, labelled by the batch size."""
+        executed and as the model has them, the chain launches whose first
+        conv's taps are folded into the contraction, and the weight bytes
+        one batch pulls from HBM, labelled by the batch size."""
         from repro.core.lower import program_stats
         from repro.obs.metrics import REGISTRY
 
         st = program_stats(self.g, self.program, batch)
         for name in ("kernel_vmem_bytes_max", "conv_macs_executed",
-                     "conv_macs_model"):
+                     "conv_macs_model", "taps_folded_launches"):
             REGISTRY.gauge(f"executor.{name}").set(st[name])
         REGISTRY.gauge("executor.weight_bytes_fetched",
                        {"batch": batch}).set(st["weight_bytes_fetched"])

@@ -293,13 +293,17 @@ def launch_vmem_bytes(g: XGraph, launch: FusedLaunch) -> int:
 def program_stats(g: XGraph, program: "GroupProgram", batch: int) -> dict:
     """What the fused launches of ``program`` occupy and do, from the
     kernel's own blocks and geometry: the largest launch's VMEM bytes, conv
-    MACs per image as executed and as the model has them, and the weight and
-    bias bytes pulled from HBM for one batch of ``batch``."""
+    MACs per image as executed and as the model has them, the weight and
+    bias bytes pulled from HBM for one batch of ``batch``, and how many
+    chain launches run their first conv with its taps folded into the
+    contraction (``ops.fold_taps``)."""
     from repro.kernels.conv_fused import ops
 
-    vmem = executed = model = fetched = 0
+    vmem = executed = model = fetched = folded = 0
     for launch in program.launches():
         operands = launch_operands(g, launch)
+        if launch.kind == "chain":
+            folded += ops.fold_taps(launch.stages, operands[1])[2]
         blocks = ops.launch_blocks(launch, *operands, batch=batch)
         vmem = max(vmem, blocks.vmem_bytes())
         fetched += blocks.param_bytes_fetched()
@@ -307,7 +311,8 @@ def program_stats(g: XGraph, program: "GroupProgram", batch: int) -> dict:
         executed += ex
         model += mo
     return {"kernel_vmem_bytes_max": vmem, "conv_macs_executed": executed,
-            "conv_macs_model": model, "weight_bytes_fetched": fetched}
+            "conv_macs_model": model, "weight_bytes_fetched": fetched,
+            "taps_folded_launches": folded}
 
 
 # ---------------------------------------------------------- strategy lowering

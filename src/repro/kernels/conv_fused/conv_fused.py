@@ -188,26 +188,35 @@ def _col_valid(shape, col0, q, true_w):
     return (cols >= q) & (cols < q + true_w)
 
 
-def _conv_acc(src, c_in, w_ref, r, out_c, kh, kw, sh, sw, dh, dw):
+def _dot_dtype(interpret: bool):
+    """Operand type of the conv matmuls: int8 on the MXU (Mosaic has no
+    int32 matmul); int32 under the interpreter, where XLA's CPU small-GEMM
+    emitter adds an int8 product into the int32 accumulator and fails to
+    compile (``add i32, i8``) for some small shapes.  The operands hold int8
+    values, so both are exact."""
+    return jnp.int32 if interpret else jnp.int8
+
+
+def _conv_acc(src, c_in, w_ref, r, out_c, kh, kw, sh, sw, dh, dw, dot_dtype):
     """int32 accumulator of output row r of a conv read from the int32 VMEM
     buffer ``src``: kh*kw (strided) window loads per channel group, each a
     matmul against that group's weight rows.  The buffer holds saturated int8
-    values, so int8 operands with int32 accumulation are exact (Mosaic has no
-    int32 matmul)."""
+    values, so int8 operands with int32 accumulation are exact."""
     acc = jnp.zeros((out_c, w_ref.shape[-1]), jnp.int32)
     for i in range(kh):
         for j in range(kw):
             for g, (lo, cw) in enumerate(_groups(c_in)):
                 patch = src[g, r * sh + i * dh, _ds(j * dw, out_c, sw), :cw]
-                acc = acc + jnp.dot(patch.astype(jnp.int8),
-                                    w_ref[i, j, lo:lo + cw],
+                acc = acc + jnp.dot(patch.astype(dot_dtype),
+                                    w_ref[i, j, lo:lo + cw].astype(dot_dtype),
                                     preferred_element_type=jnp.int32)
     return acc
 
 
-def _conv_row(src, c_in, w_ref, b_ref, st, r, out_c):
+def _conv_row(src, c_in, w_ref, b_ref, st, r, out_c, dot_dtype):
     _, _, kh, kw, sh, sw, _, _, dh, dw, shift, relu = st[:12]
-    acc = _conv_acc(src, c_in, w_ref, r, out_c, kh, kw, sh, sw, dh, dw)
+    acc = _conv_acc(src, c_in, w_ref, r, out_c, kh, kw, sh, sw, dh, dw,
+                    dot_dtype)
     y = _round_shift(acc + b_ref[...], shift)
     if relu:
         y = jnp.maximum(y, 0)
@@ -246,7 +255,7 @@ def _true_hw(st) -> tuple:
             (st[9], st[10]) if st[0] == "pool" else (st[5], st[6]))
 
 
-def _chain_kernel(*refs, chain, geom, chans):
+def _chain_kernel(*refs, chain, geom, chans, dot_dtype):
     """Every stage reads its input rows from an int32 VMEM buffer (strided
     windows are ref loads: Mosaic has no strided value slice and no strided
     int8 load) and writes its masked output rows into the next stage's
@@ -309,7 +318,8 @@ def _chain_kernel(*refs, chain, geom, chans):
 
         def body(r):
             if st[0] == "conv":
-                y = _conv_row(src, c_in, w_ref, b_ref, st, r, out_c)
+                y = _conv_row(src, c_in, w_ref, b_ref, st, r, out_c,
+                              dot_dtype)
                 for g, (lo, cw) in enumerate(_groups(y.shape[-1])):
                     emit(r, g, lo, cw, y[:, lo:lo + cw])
                 return
@@ -326,8 +336,8 @@ def _chain_kernel(*refs, chain, geom, chans):
 
 def _compiler_params(interpret: bool):
     """Scoped-VMEM limit of one launch: the budget ``hw.TPU_V5E`` plans tiles
-    under (Mosaic's 16 MiB default is too small for the stem's whole-image
-    block, ~15 MiB lane-padded and double-buffered).  The fusion search
+    under (Mosaic's 16 MiB default is too small for the whole-image input
+    blocks and stage buffers of the larger chains).  The fusion search
     admits a chain only if its ``LaunchBlocks.vmem_bytes()`` fits it."""
     return (None if interpret
             else pltpu.CompilerParams(vmem_limit_bytes=V5E_VMEM_BYTES))
@@ -491,7 +501,8 @@ def fused_chain_pallas(x_pad, weights, biases, sides, *, chain, th, toc,
     blocks = chain_blocks(chain, geom, x_pad.shape, [w.shape for w in weights],
                           [s.shape for s in sides], toc=toc, oc=oc)
     kern = functools.partial(_chain_kernel, chain=chain, geom=geom,
-                             chans=blocks.chans)
+                             chans=blocks.chans,
+                             dot_dtype=_dot_dtype(interpret))
     fn = _pallas(kern, blocks, (n, geom["n_h"] * th, geom["n_w"] * geom["tw"],
                                 oc), interpret)
     args = [x_pad]
@@ -502,7 +513,7 @@ def fused_chain_pallas(x_pad, weights, biases, sides, *, chain, th, toc,
 
 # ------------------------------------------------------ horizontal (stacked)
 def _horizontal_kernel(x_ref, w_ref, b_ref, s_ref, r_ref, o_ref, buf, *,
-                       kh, kw, sh, sw, th, tw, n_w, ic):
+                       kh, kw, sh, sw, th, tw, n_w, ic, dot_dtype):
     j = pl.program_id(1)
     jw = pl.program_id(2)
     in_rows, in_cols = buf.shape[1:3]
@@ -516,7 +527,8 @@ def _horizontal_kernel(x_ref, w_ref, b_ref, s_ref, r_ref, o_ref, buf, *,
     _rows(in_rows, stage_in)
 
     def body(r):
-        acc = _conv_acc(buf, ic, w_ref, r, tw, kh, kw, sh, sw, 1, 1)
+        acc = _conv_acc(buf, ic, w_ref, r, tw, kh, kw, sh, sw, 1, 1,
+                        dot_dtype)
         y = _round_shift_vec(acc + b_ref[...], s_ref[...])
         y = jnp.where(r_ref[...] != 0, jnp.maximum(y, 0), y)
         o_ref[0, r] = _sat8(y)
@@ -539,7 +551,8 @@ def fused_horizontal_pallas(x_pad, w, b, shift_vec, relu_vec, *, stride,
                                tw=tw, toc=toc, oh=oh, ow=ow)
     _, n_h, n_w, _ = blocks.grid
     kern = functools.partial(_horizontal_kernel, kh=kh, kw=kw, sh=stride[0],
-                             sw=stride[1], th=th, tw=tw, n_w=n_w, ic=ic)
+                             sw=stride[1], th=th, tw=tw, n_w=n_w, ic=ic,
+                             dot_dtype=_dot_dtype(interpret))
     fn = _pallas(kern, blocks, (n, n_h * th, n_w * tw, w.shape[-1]),
                  interpret)
     return fn(x_pad, w, b, shift_vec, relu_vec)[:, :oh, :ow]

@@ -11,6 +11,8 @@
 ``launch_blocks``   — the grid and blocks one launch runs with: its VMEM
                       footprint and the weight bytes it pulls from HBM.
 ``launch_macs``     — the conv MACs one launch executes and the model's.
+``fold_taps``       — the chain a launch runs: a lane-starved first conv's
+                      taps folded into its contraction.
 """
 from __future__ import annotations
 
@@ -22,7 +24,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.kernels.conv_fused.conv_fused import (
-    I8_MIN, LaunchBlocks, _true_hw, chain_blocks, chain_geometry,
+    I8_MIN, LANES, LaunchBlocks, _true_hw, chain_blocks, chain_geometry,
     fused_chain_pallas, fused_horizontal_pallas, horizontal_blocks)
 
 
@@ -116,6 +118,46 @@ def _horizontal_pad(kernel, stride, pad, oh: int, ow: int, th: int,
             (-(-ow // tw) * tw - 1) * sw + kw)
 
 
+def fold_taps(chain, weight_shapes) -> tuple:
+    """(chain, weight shapes, folded) of the launch the kernel runs.
+
+    A first conv with several taps whose KH*KW*IC fits in two 128-lane
+    groups (an image stem: IC = 3) would run every tap as a matmul only IC
+    deep.  Over the im2col patches of its padded input (``_patches``) it is
+    one 1x1/s1/p0 conv KH*KW*IC deep, its panel reshaped (KH, KW, IC, OC) ->
+    (1, 1, KH*KW*IC, OC) in the same (i, j, ch) order: the same int8
+    products summed in int32, so bit-exact.  Its output extents, and so
+    every downstream stage's geometry and mask, stay as they were.  Decided
+    by shapes alone, for the launcher, the search's VMEM admission and the
+    program counters alike."""
+    if not chain or chain[0][0] != "conv":
+        return chain, weight_shapes, False
+    kh, kw, ic, oc = weight_shapes[0]
+    k = kh * kw * ic
+    if kh * kw == 1 or k > 2 * LANES:
+        return chain, weight_shapes, False
+    first = ("conv", chain[0][1], 1, 1, 1, 1, 0, 0, 1, 1) + chain[0][10:]
+    return ((first,) + tuple(chain[1:]),
+            [(1, 1, k, oc)] + list(weight_shapes[1:]), True)
+
+
+def _patches(xp, st, h: int, w: int):
+    """``P[n, r, c, (i, j, ch)] = xp[n, r*sh + i*dh, c*sw + j*dw, ch]`` for
+    the (h, w) output positions of conv stage ``st`` (padded coordinates):
+    one convolution with one-hot filters, each output a single int8 input
+    (exact).  The chip runs it as one op with the pad fused in; strided
+    slices would each become a gather."""
+    _, _, kh, kw, sh, sw, _, _, dh, dw = st[:10]
+    ic = xp.shape[-1]
+    k = kh * kw * ic
+    eye = jnp.eye(k, dtype=xp.dtype).reshape(kh, kw, ic, k)
+    xp = xp[:, :(h - 1) * sh + (kh - 1) * dh + 1,
+            :(w - 1) * sw + (kw - 1) * dw + 1]
+    return jax.lax.conv_general_dilated(
+        xp, eye, (sh, sw), "VALID", rhs_dilation=(dh, dw),
+        dimension_numbers=("NHWC", "HWIO", "NHWC"))
+
+
 @partial(jax.jit, static_argnames=("chain", "oh", "ow", "oc", "interpret",
                                    "tile"))
 def _run_chain(x, weights, biases, sides, *, chain, oh, ow, oc, interpret,
@@ -125,10 +167,15 @@ def _run_chain(x, weights, biases, sides, *, chain, oh, ow, oc, interpret,
     geom = chain_geometry(chain, th, oh, ow, tw)
     xp = _pad_to(x, geom["q_in"][0], geom["q_in"][1],
                  geom["h_req"], geom["w_req"], geom["fill0"])
+    run, shapes, folded = fold_taps(chain, [w.shape for w in weights])
+    if folded:
+        fg = chain_geometry(run, th, oh, ow, tw)
+        xp = _patches(xp, chain[0], fg["h_req"], fg["w_req"])
+        weights = (weights[0].reshape(shapes[0]),) + tuple(weights[1:])
     sp = tuple(_pad_to(s, sg["q"][0], sg["q"][1], sg["h_req"], sg["w_req"], 0)
                for s, sg in zip(sides, geom["sides"]))
     biases = tuple(b.reshape(1, -1) for b in biases)
-    return fused_chain_pallas(xp, weights, biases, sp, chain=chain, th=th,
+    return fused_chain_pallas(xp, weights, biases, sp, chain=run, th=th,
                               tw=tw, toc=toc, oh=oh, ow=ow, oc=oc,
                               interpret=interpret)
 
@@ -175,9 +222,12 @@ def launch_blocks(launch, in_hwc, weight_shapes, side_hwcs=(),
         return horizontal_blocks((batch, hp, wp, c), ws,
                                  stride=tuple(launch.stride), th=th, tw=tw,
                                  toc=toc, oh=oh, ow=ow)
-    chain = launch.stages
+    chain, weight_shapes, folded = fold_taps(launch.stages, weight_shapes)
     _, _, toc, oc, geom = _chain_tile(chain, weight_shapes, c, launch.tile)
-    hp, wp = _padded_hw(h, w, *geom["q_in"], geom["h_req"], geom["w_req"])
+    if folded:       # the patches span exactly the first conv's output
+        hp, wp, c = geom["h_req"], geom["w_req"], weight_shapes[0][2]
+    else:
+        hp, wp = _padded_hw(h, w, *geom["q_in"], geom["h_req"], geom["w_req"])
     sides = [(batch,) + _padded_hw(sh, sw, *sg["q"], sg["h_req"],
                                    sg["w_req"]) + (sc,)
              for (sh, sw, sc), sg in zip(side_hwcs, geom["sides"])]
@@ -199,7 +249,7 @@ def launch_macs(launch, in_hwc, weight_shapes) -> tuple:
         per_pos = kh * kw * ic * oc
         return (-(-oh // th) * th * -(-ow // tw) * tw * per_pos,
                 oh * ow * per_pos)
-    chain = launch.stages
+    chain, weight_shapes, _ = fold_taps(launch.stages, weight_shapes)
     _, _, toc, oc, geom = _chain_tile(chain, weight_shapes, in_hwc[-1],
                                       launch.tile)
     conv_idx = [i for i, st in enumerate(chain) if st[0] == "conv"]

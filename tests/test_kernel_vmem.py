@@ -35,6 +35,11 @@ def vgg16():
     return _plan("vgg16")
 
 
+@pytest.fixture(scope="module")
+def googlenet():
+    return _plan("googlenet")
+
+
 def _launch(prog, first):
     return next(it for it in prog.launches() if it.nodes[0] == first)
 
@@ -224,7 +229,57 @@ def test_executor_exports_its_launch_counters(rng):
     assert want["conv_macs_executed"] >= want["conv_macs_model"] > 0
     assert 0 < want["kernel_vmem_bytes_max"] <= V5E_VMEM_BYTES
     for name in ("kernel_vmem_bytes_max", "conv_macs_executed",
-                 "conv_macs_model"):
+                 "conv_macs_model", "taps_folded_launches"):
         assert REGISTRY.get(f"executor.{name}").value == want[name]
     assert (REGISTRY.get("executor.weight_bytes_fetched{batch=3}").value
             == want["weight_bytes_fetched"] > 0)
+
+
+def test_vgg16_program_is_pinned():
+    """VGG-16's 8 launches and softmax fallback (``launch_pins.json``, made
+    by ``_launch_list`` on the tree before the stem's taps were folded), and
+    the 13 segments the kernel-VMEM check rejects on the way."""
+    name = "pathsearch.segments_rejected{reason=exceeds_kernel_vmem}"
+    before = REGISTRY.get(name)
+    before = before.value if before is not None else 0
+    with open(PINS) as f:
+        want = json.load(f)["vgg16-224"]
+    _, prog = _plan("vgg16")
+    assert _launch_list(prog) == want
+    assert REGISTRY.get(name).value - before == 13
+
+
+# lower.program_stats at batch 32 of the programs before the fold
+UNFOLDED_STATS = {
+    "resnet50": {"conv_macs_executed": 4_849_230_144,
+                 "conv_macs_model": 4_089_184_256,
+                 "weight_bytes_fetched": 793_332_576},
+    "googlenet": {"conv_macs_executed": 1_641_486_656,
+                  "conv_macs_model": 1_582_671_872,
+                  "weight_bytes_fetched": 221_331_744},
+    "vgg16": {"conv_macs_executed": 23_576_422_912,
+              "conv_macs_model": 15_470_264_320,
+              "weight_bytes_fetched": 3_845_081_184},
+}
+
+
+@pytest.mark.parametrize("net", sorted(UNFOLDED_STATS))
+def test_stem_taps_fold_and_the_counters_hold(net, request, monkeypatch):
+    """Each program folds exactly its stem (IC = 3) into one contraction:
+    the MACs and weight bytes it counts are what they were unfolded, and
+    the footprint the search admits the folded stem by is what the kernel
+    allocates for it."""
+    from repro.kernels.conv_fused import ops
+
+    g, prog = request.getfixturevalue(net)
+    stats = lower.program_stats(g, prog, 32)
+    assert stats["taps_folded_launches"] == 1
+    assert {k: stats[k] for k in UNFOLDED_STATS[net]} == UNFOLDED_STATS[net]
+    stem = prog.launches()[0]
+    operands = lower.launch_operands(g, stem)
+    assert stem.nodes[0] == "conv1" and operands[1][0][2] == 3
+    assert ops.fold_taps(stem.stages, operands[1])[2]
+    got, grid = _allocated(g, stem, monkeypatch)
+    blocks = ops.launch_blocks(stem, *operands, batch=2)
+    assert blocks.grid == grid
+    assert blocks.vmem_bytes() == got

@@ -187,3 +187,78 @@ def test_horizontal_stacked_bit_exact():
         stride=(1, 1), pad=(1, 1), oh=10, ow=10, interpret=interpret_mode())
     np.testing.assert_array_equal(np.asarray(y[..., :8]), np.asarray(ya))
     np.testing.assert_array_equal(np.asarray(y[..., 8:]), np.asarray(yb))
+
+
+def _conv_stage(name, k, s, p, d, shift, relu, oh):
+    return ("conv", name, k, k, s, s, p, p, d, d, shift, relu, oh, oh)
+
+
+# (x shape, [(k, stride, pad, dilation, oc)], pool (k, s) or None, tile)
+FOLD_CASES = {
+    # ResNet's stem: 7x7/2 conv, IC 3, then a ceil-mode 3x3/2 max pool
+    "stem_7x7s2_ceil_pool": ((2, 29, 29, 3), [(7, 2, 3, 1, 16)], (3, 2),
+                             (4, 7, 16)),
+    # VGG's conv1_1 into a second 3x3 conv, th < OH: halo rows recomputed
+    "3x3_into_3x3_halo": ((1, 12, 12, 3), [(3, 1, 1, 1, 8), (3, 1, 1, 1, 8)],
+                          None, (4, 12, 8)),
+    # ragged row and width tiles: 13 = 4 + 4 + 4 + 1 rows, 8 + 5 columns
+    "ragged_width_tile": ((1, 13, 13, 3), [(3, 1, 1, 1, 8)], None, (4, 8, 8)),
+    "dilated_3x3": ((1, 12, 12, 3), [(3, 1, 2, 2, 8)], None, ()),
+    # 5x5x6 = 150 contraction rows: two 128-lane groups
+    "two_lane_groups": ((1, 10, 10, 6), [(5, 1, 2, 1, 8)], None, (5, 10, 8)),
+}
+
+
+@pytest.mark.parametrize("case", sorted(FOLD_CASES))
+def test_folded_first_conv_bit_exact(case):
+    """A lane-starved first conv runs as one matmul over its im2col patches
+    (``ops.fold_taps``), bit-exact with the int8 oracle downstream of any
+    pool, second conv or ragged tile."""
+    import math
+
+    from repro.core import int8_ops
+    from repro.kernels.conv_fused.ops import _run_chain, fold_taps
+
+    shape, convs, pool, tile = FOLD_CASES[case]
+    rng = np.random.default_rng(sum(map(ord, case)))
+    x = jnp.asarray(rng.integers(-128, 128, shape).astype(np.int8))
+    y, chain, ws, bs = x, [], [], []
+    for n, (k, s, p, d, oc) in enumerate(convs):
+        ic = y.shape[-1]
+        w = jnp.asarray(rng.integers(-128, 128, (k, k, ic, oc)).astype(np.int8))
+        b = jnp.asarray(rng.integers(-2000, 2000, oc).astype(np.int32))
+        shift = 7 + n
+        y = int8_ops.conv2d(y, w, b, stride=(s, s), pad=(p, p),
+                            dilation=(d, d), shift=shift, relu=True)
+        chain.append(_conv_stage(f"c{n}", k, s, p, d, shift, True,
+                                 y.shape[1]))
+        ws.append(w)
+        bs.append(b)
+    if pool:
+        kp, sp = pool
+        y = int8_ops.maxpool(y, kernel=(kp, kp), stride=(sp, sp),
+                             ceil_mode=True)
+        oh = math.ceil((chain[-1][12] - kp) / sp) + 1
+        assert y.shape[1] == oh
+        chain.append(("pool", "p", "max", kp, kp, sp, sp, 0, 0, oh, oh,
+                      kp * kp))
+    chain = tuple(chain)
+    assert fold_taps(chain, [w.shape for w in ws])[2]
+    oh = y.shape[1]
+    got = _run_chain(x, tuple(ws), tuple(bs), (), chain=chain, oh=oh, ow=oh,
+                     oc=y.shape[-1], interpret=interpret_mode(), tile=tile)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(y))
+
+
+@pytest.mark.parametrize("first,w_shape", [
+    (_conv_stage("c", 3, 1, 1, 1, 6, True, 8), (3, 3, 64, 64)),
+    (_conv_stage("fc", 1, 1, 0, 1, 6, False, 1), (1, 1, 4096, 1000)),
+    (_conv_stage("c", 1, 1, 0, 1, 6, True, 8), (1, 1, 3, 64)),
+], ids=["3x3_ic64", "fc", "1x1_ic3"])
+def test_fold_leaves_full_or_single_tap_convs(first, w_shape):
+    """A conv whose taps fill more than two lane groups, or that has one
+    tap, runs as it is."""
+    from repro.kernels.conv_fused.ops import fold_taps
+
+    chain = (first,)
+    assert fold_taps(chain, [w_shape]) == (chain, [w_shape], False)
