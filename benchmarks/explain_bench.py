@@ -123,17 +123,18 @@ def scrape_mid_serve(g, qm, strategy, dev, model: str, x) -> dict:
     """Serve the plan and GET /explain/<model> while requests are in flight."""
     from repro import asm
     from repro.core import quantize
-    from repro.explain import validate_report
+    from repro.explain import live_report, validate_report
     from repro.runtime import Session
 
-    sess = Session(g, strategy, dev, qm, backend="pallas",
-                   cache=asm.PlanCache())
+    art = asm.compile_strategy(g, strategy, dev, qm=qm)
+    sess = Session.from_artifact(art, backend="pallas")
     rng = np.random.default_rng(1)
     reqs = [quantize.quantize_to(
         rng.standard_normal((1,) + tuple(g.shape("data")[1:]))
         .astype(np.float32), qm.f_a["data"]) for _ in range(8)]
     with sess.serve(max_batch=4, labels={"model": model}) as srv:
         obs = srv.serve_metrics(port=0)
+        obs.add_explain(model, lambda: live_report(art))
         futs = [srv.submit(r) for r in reqs]
         with urllib.request.urlopen(obs.url("/explain")) as r:
             models = json.load(r)["models"]

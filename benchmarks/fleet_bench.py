@@ -1,7 +1,7 @@
 """Fleet benchmark + chaos gate: replicated serving under injected faults.
 
-Three phases, all against ONE shared ``CompiledArtifact`` (every replica's
-plan cache is seeded from it — the fleet compiles nothing):
+Three phases, all against ONE shared ``CompiledArtifact`` (every replica
+dispatches its stored program — the fleet compiles nothing):
 
 * **scaling** — the same burst served by a 1-replica and a 2-replica fleet,
   with a uniform per-launch device cost injected through the chaos hook
@@ -73,8 +73,8 @@ def main(argv=None) -> dict:
     from repro.obs.metrics import MetricsRegistry
     from repro.runtime import AdmissionError, ChaosInjector, Fleet
 
-    sess, compile_times = build_session(args.model, args.img, "ref", True)
-    art = sess.artifact
+    sess, art, _, compile_times = build_session(args.model, args.img, "ref",
+                                                True)
     reqs = make_requests(sess, args.requests)
     import jax
     print(f"{args.model}@{args.img} requests={args.requests} "

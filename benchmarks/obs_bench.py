@@ -33,7 +33,10 @@ import numpy as np
 
 
 def build_profiled_session(model: str, img: int, backend: str):
-    """Graph + sim-calibrated profile + profile-guided compiled session."""
+    """Sim-calibrated profile, the profile-guided artifact compiled through
+    the plan cache, and a session serving it: (session, artifact, profile,
+    simulator)."""
+    from repro import asm
     from repro.cnn import build, init_params
     from repro.core import executor, pathsearch, quantize
     from repro.core.cost import SimulatorEvaluator
@@ -51,8 +54,8 @@ def build_profiled_session(model: str, img: int, backend: str):
                     features="analytic")
     p = res.profile
     s = pathsearch.search(g, ZU2, evaluator=CalibratedEvaluator(g, ZU2, p))
-    sess = Session(g, s, ZU2, qm, backend=backend, profile=p)
-    return sess, sim
+    art, _ = asm.PLAN_CACHE.get_or_compile(g, s, ZU2, qm=qm, profile=p)
+    return Session.from_artifact(art, backend=backend), art, p, sim
 
 
 def sim_measure_fn(sess, sim):
@@ -149,21 +152,22 @@ def main(argv=None) -> dict:
 
     # tracer on from the start: the compile pipeline below lands in the trace
     TRACER.enable()
-    sess, sim = build_profiled_session(args.model, args.img, args.backend)
+    sess, art, prof, sim = build_profiled_session(args.model, args.img,
+                                                  args.backend)
     reqs = [np.asarray(x, np.int8) for x in
             np.random.default_rng(1).integers(
                 -128, 128, (args.requests,) + tuple(
                     sess.graph.shape("data")[1:]))]
     print(f"{args.model}@{args.img} backend={args.backend} "
           f"requests={args.requests} "
-          f"fused_coverage={sess.artifact.fused_coverage:.2f} "
-          f"profile={sess.profile.hash()}")
+          f"fused_coverage={art.fused_coverage:.2f} "
+          f"profile={prof.hash()}")
 
     # warm every allowed batch shape outside all timed windows
     serve_once(sess, reqs[:args.max_batch], max_batch=args.max_batch,
                max_latency_s=args.max_latency_ms * 1e-3)
 
-    dp = DriftProfiler.from_session(sess, every=args.drift_every,
+    dp = DriftProfiler.from_session(sess, art, prof, every=args.drift_every,
                                     measure_fn=sim_measure_fn(sess, sim))
 
     # alternate untraced / traced+profiled trials; best-of each mode
@@ -199,10 +203,11 @@ def main(argv=None) -> dict:
                                   min_samples=8, cooldown_s=1.0))
     # gold, but with an attainable target: this phase measures overhead,
     # the violation is induced separately below
-    ms.add_model(args.model, sess, slo="gold", target_p99_ms=1e4,
-                 warmup=False, max_batch=args.max_batch,
+    # the tenant's session is a fresh executor: warm its batch shapes here
+    ms.add_model(args.model, art, slo="gold", target_p99_ms=1e4,
+                 backend=args.backend, max_batch=args.max_batch,
                  max_latency_s=args.max_latency_ms * 1e-3)
-    ms.attach_drift(args.model, every=args.drift_every,
+    ms.attach_drift(args.model, profile=prof, every=args.drift_every,
                     measure_fn=sim_measure_fn(sess, sim))
     http = ms.serve_metrics()
     plane, scraped = 0.0, ""
@@ -236,7 +241,8 @@ def main(argv=None) -> dict:
                       burn_kw=dict(fast_window_s=30.0, slow_window_s=60.0,
                                    min_samples=4, cooldown_s=0.0))
     hot = f"{args.model}_hot"
-    ms2.add_model(hot, sess, slo="gold", target_p99_ms=1e-6, warmup=False,
+    ms2.add_model(hot, art, slo="gold", target_p99_ms=1e-6,
+                  backend=args.backend, warmup=False,
                   max_batch=args.max_batch,
                   max_latency_s=args.max_latency_ms * 1e-3)
     for f in [ms2.submit(hot, x) for x in reqs[:12]]:
@@ -252,7 +258,9 @@ def main(argv=None) -> dict:
     ms.close()
 
     # modeled engine timeline of the same plan, as a parallel trace process
-    rep = sess.pipeline_report(min(args.requests, 4), ddr_slots=None)
+    from repro.runtime import pipeline_report
+    rep = pipeline_report(art, min(args.requests, 4), ddr_slots=None,
+                          profile=prof)
     n_modeled = TRACER.add_engine_windows(rep.engine_timeline, ZU2.freq_hz)
     print(f"modeled track: {n_modeled} engine windows "
           f"(ddr_slots={rep.ddr_slots}, source={rep.ddr_slots_source})")

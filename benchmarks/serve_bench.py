@@ -1,8 +1,8 @@
 """Serving benchmark: dynamic batching vs sequential per-request execution.
 
 What Table 3 is to the compiler, this is to the runtime supporter: build a
-model, compile it once through the plan cache, then serve R requests two
-ways —
+model, compile it once to a DNNVM object file, then serve R requests from
+it two ways —
 
 * **sequential**: one `Session.run` per request, back to back (the naive
   host loop every toolflow starts with);
@@ -31,11 +31,14 @@ import numpy as np
 
 def build_session(model: str, img: int, backend: str, use_host_partition: bool,
                   profile=None):
+    """(session, the artifact it serves, the resolved profile or None,
+    search/compile seconds)."""
+    from repro import asm
     from repro.cnn import build, init_params
     from repro.core import executor, partition, pathsearch, quantize
     from repro.hw import ZU2
     from repro.runtime import Session
-    from repro.runtime.session import _resolve_profile
+    from repro.tune.profile import resolve_profile
 
     g = build(model, img=img, num_classes=10) if img != 224 else build(model)
     params = init_params(g)
@@ -43,7 +46,7 @@ def build_session(model: str, img: int, backend: str, use_host_partition: bool,
     x = rng.standard_normal(g.shape("data")).astype(np.float32)
     qm = quantize.calibrate(g, params, x, executor.run_float)
     dv = partition.device_of(g, "paper") if use_host_partition else None
-    profile = _resolve_profile(profile)
+    profile = resolve_profile(profile)
     evaluator = None
     if profile is not None:
         from repro.tune import CalibratedEvaluator
@@ -52,20 +55,21 @@ def build_session(model: str, img: int, backend: str, use_host_partition: bool,
     strategy = pathsearch.search(g, ZU2, evaluator=evaluator, device_of=dv)
     t_search = time.perf_counter() - t0
     t0 = time.perf_counter()
-    sess = Session(g, strategy, ZU2, qm, backend=backend, profile=profile)
+    art = asm.compile_strategy(g, strategy, ZU2, qm=qm, profile=profile)
+    sess = Session.from_artifact(art, backend=backend)
     t_compile = time.perf_counter() - t0
-    return sess, {"search_s": t_search, "compile_s": t_compile}
+    return sess, art, profile, {"search_s": t_search, "compile_s": t_compile}
 
 
-def drift_summary(sess) -> dict:
-    """Modeled-vs-measured drift of the served plan, when the session carries
-    a device profile (see ``repro.obs.drift``); cheap to skip when it
-    doesn't — serve_bench's default analytic run has nothing to drift from."""
-    if sess.profile is None:
+def drift_summary(sess, art, profile) -> dict:
+    """Modeled-vs-measured drift of the served plan, when it was compiled
+    under a device profile (see ``repro.obs.drift``); cheap to skip when it
+    wasn't — serve_bench's default analytic run has nothing to drift from."""
+    if profile is None:
         return {"available": False, "reason": "no device profile"}
     from repro.obs import DriftProfiler
 
-    dp = DriftProfiler.from_session(sess, every=1)
+    dp = DriftProfiler.from_session(sess, art, profile, every=1)
     dp.prepare()
     dp.sample()
     rep = dp.report()
@@ -178,13 +182,13 @@ def main(argv=None) -> dict:
     if args.smoke and args.repeats < 3:
         args.repeats = 3
 
-    sess, compile_times = build_session(
+    sess, art, profile, compile_times = build_session(
         args.model, args.img, args.backend, args.host_partition,
         profile=args.profile)
     reqs = make_requests(sess, args.requests)
     print(f"{args.model}@{args.img} backend={args.backend} "
           f"requests={args.requests} fused_coverage="
-          f"{sess.artifact.fused_coverage:.2f} "
+          f"{art.fused_coverage:.2f} "
           f"(search {compile_times['search_s']:.2f}s, "
           f"compile {compile_times['compile_s']:.2f}s)")
 
@@ -223,11 +227,13 @@ def main(argv=None) -> dict:
 
     # pinned-input variant of the same plan: the input's DDR region leaves
     # the reuse pool, the cross-request pre-load guard disappears
+    from repro import asm
     from repro.runtime.schedule import choose_ddr_slots
     from repro.runtime.schedule import pipeline_report as _pipe_report
-    pinned_art, _ = sess.cache.get_or_compile(
-        sess.graph, sess.artifact, sess.device, qm=sess.qm, pin_input=True)
-    auto_rep = sess.pipeline_report(min(args.requests, 8), ddr_slots=None)
+    pinned_art = asm.compile_strategy(sess.graph, art, sess.device,
+                                      qm=sess.qm, pin_input=True)
+    auto_rep = _pipe_report(art, min(args.requests, 8), ddr_slots=None,
+                            profile=profile)
     print(f"auto ddr_slots: {auto_rep.ddr_slots} "
           f"(source={auto_rep.ddr_slots_source}, DRAM/compute ratio decides "
           f"the double-buffer depth)")
@@ -236,7 +242,7 @@ def main(argv=None) -> dict:
                      "modeled_speedup": auto_rep.modeled_speedup,
                      "overlap": auto_rep.overlap}}
     for slots in args.ddr_slots:
-        rep = sess.pipeline_report(min(args.requests, 8), ddr_slots=slots)
+        rep = _pipe_report(art, min(args.requests, 8), ddr_slots=slots)
         repp = _pipe_report(pinned_art, min(args.requests, 8),
                             ddr_slots=slots)
         pipe[slots] = {
@@ -260,7 +266,7 @@ def main(argv=None) -> dict:
         print(f"  pin_input: overlap {rep.overlap:.2%} -> {repp.overlap:.2%}, "
               f"pre-load guards {rep.n_preload_guards} -> "
               f"{repp.n_preload_guards}, peak DDR "
-              f"{sess.artifact.peak_ddr_bytes} -> {pinned_art.peak_ddr_bytes}B")
+              f"{art.peak_ddr_bytes} -> {pinned_art.peak_ddr_bytes}B")
 
     # observability payload: the shared metrics registry has been counting
     # the whole run (plan cache, executor launches, serve histograms); the
@@ -268,7 +274,7 @@ def main(argv=None) -> dict:
     # predictions when the session was compiled under one
     from repro.obs import REGISTRY
     metrics_snapshot = REGISTRY.snapshot()
-    drift = drift_summary(sess)
+    drift = drift_summary(sess, art, profile)
     if drift["available"]:
         print(f"drift: aggregate={drift['aggregate_deviation']:.3f} "
               f"band={drift['band']:.3f} drifted={drift['drifted']}")
@@ -277,7 +283,7 @@ def main(argv=None) -> dict:
         "model": args.model, "img": args.img, "backend": args.backend,
         "requests": args.requests, "max_batch": args.max_batch,
         "max_latency_ms": args.max_latency_ms,
-        "fused_coverage": sess.artifact.fused_coverage,
+        "fused_coverage": art.fused_coverage,
         **compile_times,
         "sequential": {k: v for k, v in seq.items() if k != "outputs"},
         "batched": sweeps,

@@ -123,7 +123,7 @@ def bench_model(model: str, img: int, *, backend: str, max_samples: int,
         rec["measured_delta"] = 0.0       # same plan, same launches
 
     # the calibrated strategy compiles under the profile; the artifact
-    # records the hash (Session.from_artifact warns on mismatch)
+    # records the hash (a drift profiler checks it against its profile)
     art, _ = asm.PlanCache().get_or_compile(g, s_cal, dev, qm=qm,
                                             profile=res.profile)
     rec["artifact_profile_hash"] = art.profile_hash

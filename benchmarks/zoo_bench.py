@@ -92,15 +92,16 @@ def run_swapped(artifacts: dict, reqs_by_model: dict, backend: str) -> dict:
             "swap_s": swap_s}
 
 
-def run_multiserver(sessions: dict, stream, reqs_by_model: dict, *,
-                    max_batch: int, max_latency_s: float) -> dict:
+def run_multiserver(artifacts: dict, stream, reqs_by_model: dict, *,
+                    backend: str, max_batch: int,
+                    max_latency_s: float) -> dict:
     from repro.runtime import MultiServer
 
-    names = list(sessions)
+    names = list(artifacts)
     ms = MultiServer()
     for name, slo in zip(names, SLO_ORDER):
-        ms.add_model(name, sessions[name], slo=slo, max_batch=max_batch,
-                     max_latency_s=max_latency_s)
+        ms.add_model(name, artifacts[name], slo=slo, backend=backend,
+                     max_batch=max_batch, max_latency_s=max_latency_s)
     try:
         cursors = {m: 0 for m in names}
         futs = []
@@ -215,14 +216,14 @@ def main(argv=None) -> dict:
                      for m in args.models}
     print(f"traffic: {counts} (mix {args.mix}, {args.requests} total)")
 
+    artifacts = {m: compiled[m].artifact for m in args.models}
     swapped = multi = None
     for _ in range(max(1, args.repeats)):
-        got = run_swapped({m: compiled[m].artifact for m in args.models},
-                          reqs_by_model, args.backend)
+        got = run_swapped(artifacts, reqs_by_model, args.backend)
         if swapped is None or got["images_per_s"] > swapped["images_per_s"]:
             swapped = got
-        got = run_multiserver(sessions, stream, reqs_by_model,
-                              max_batch=args.max_batch,
+        got = run_multiserver(artifacts, stream, reqs_by_model,
+                              backend=args.backend, max_batch=args.max_batch,
                               max_latency_s=args.max_latency_ms * 1e-3)
         if multi is None or got["images_per_s"] > multi["images_per_s"]:
             multi = got

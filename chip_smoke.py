@@ -87,7 +87,7 @@ def serve_one_chip(g, qm, xs, log=print) -> dict:
 
     t0 = time.perf_counter()
     strategy = pathsearch.search(g, TPU_V5E)
-    sess = Session(g, strategy, TPU_V5E, qm, backend="pallas", profile=None)
+    sess = Session(g, strategy, TPU_V5E, qm, backend="pallas")
     prog = sess.executor.program
     for b in (1, 4):                     # the served batch shapes
         sess.run_batch(xs[:b], pad_to=b)

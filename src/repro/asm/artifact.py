@@ -301,18 +301,6 @@ class CompiledArtifact:
             g.add(nd["op"], nd["name"], tuple(nd["inputs"]), **attrs)
         return g
 
-    def executor(self, g: XGraph | None = None, backend: str = "ref"):
-        from repro.core.executor import Int8Executor
-        return Int8Executor(g if g is not None else self.rebuild_graph(),
-                            self.quantized_model(), strategy=self,
-                            backend=backend)
-
-    def session(self, backend: str = "ref", **kw):
-        """Open a runtime-supporter :class:`~repro.runtime.session.Session`
-        on this artifact (seeds the plan cache; no recompilation)."""
-        from repro.runtime import Session
-        return Session.from_artifact(self, backend=backend, **kw)
-
     # ------------------------------------------------------------ round trip
     def save(self, path: str) -> None:
         """Persist as one DNNVM object file (see :func:`save_artifact`)."""
@@ -619,8 +607,7 @@ class PlanCache:
 
     @property
     def max_entries(self) -> int:
-        """Bound on resident plans (alias of ``maxsize``; a many-model server
-        sets this through ``Session``/``MultiServer``)."""
+        """Bound on resident plans (alias of ``maxsize``)."""
         return self.maxsize
 
     @max_entries.setter

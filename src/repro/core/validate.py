@@ -116,7 +116,8 @@ def artifact_round_trip(g: XGraph, qm: QuantizedModel, x: np.ndarray,
         "graph signature drifted through the artifact round trip"
 
     mem = Int8Executor(g, qm, strategy=art, backend=backend)(x)
-    got = loaded.executor(backend=backend)(x)
+    got = Int8Executor(loaded.rebuild_graph(), loaded.quantized_model(),
+                       strategy=loaded, backend=backend)(x)
     ref = Int8Executor(g, qm, strategy=None, backend="ref")(x)
     assert set(ref) == set(got) == set(mem), "output sets differ"
     max_diff, exact = 0, True

@@ -15,17 +15,19 @@ every one of those decisions inspectable after the fact:
 - :func:`render_report` / :func:`render_diff` are the deterministic text
   renderers behind ``python -m repro.explain``.
 
-Runtime surfaces: ``Session.explain()`` joins the static report with live
-drift samples; ``ObsHTTPServer`` serves ``/explain/<model>``; the event log
-carries ``explain.report`` / ``plan.diff`` events.
+Runtime surfaces: :func:`live_report` joins an artifact's report with the
+live drift samples of the deployment serving it; ``ObsHTTPServer`` serves
+``/explain/<model>``; the event log carries ``explain.report`` /
+``plan.diff`` events.
 """
 from repro.explain.diff import diff, diff_artifacts, diff_reports, negate
 from repro.explain.render import render_diff, render_report
-from repro.explain.report import (REPORT_VERSION, build_report, report_of,
-                                  validate_report)
+from repro.explain.report import (REPORT_VERSION, build_report, live_report,
+                                  report_of, validate_report)
 
 __all__ = [
-    "REPORT_VERSION", "build_report", "report_of", "validate_report",
+    "REPORT_VERSION", "build_report", "live_report", "report_of",
+    "validate_report",
     "diff", "diff_artifacts", "diff_reports", "negate",
     "render_report", "render_diff",
 ]

@@ -30,7 +30,8 @@ def render_report(rep: dict, *, drift: list | None = None,
     """Render one CompileReport as an aligned text document.
 
     ``drift`` (optional) is the live measured-vs-predicted join produced by
-    ``Session.explain()`` — rendered as an extra section when present."""
+    ``live_report`` (its ``["drift"]["units"]``) — rendered as an extra
+    section when present."""
     L: list[str] = []
     prof = rep.get("profile_name") or rep.get("profile_hash") or "analytic"
     L.append(f"== compile report: {rep['model']} on {rep['device']} "

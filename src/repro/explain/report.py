@@ -241,6 +241,37 @@ def report_of(art) -> dict:
     }
 
 
+def live_report(art, drift=None) -> dict:
+    """``report_of(art)`` as a deployment serving ``art`` explains it.
+
+    With ``drift`` (an ``obs.DriftProfiler`` on the served program) the
+    report gains a ``drift`` section: per-unit measured-vs-predicted seconds,
+    keyed like the report's groups ("|"-joined), the static plan's
+    predictions next to what this deployment measures.  Emits one
+    ``explain.report`` event; ``render_report(rep, drift=rep["drift"]
+    ["units"])`` renders the join."""
+    from repro.obs.events import EVENTS
+
+    rep = dict(report_of(art))
+    rows = None
+    if drift is not None:
+        dr = drift.report()
+        rows = [{"key": u.key.replace("+", "|"), "kind": u.kind,
+                 "predicted": u.predicted, "measured": u.measured,
+                 "deviation": u.deviation, "n_samples": u.n_samples}
+                for u in dr.units]
+        rep["drift"] = {"units": rows, "drifted": bool(dr.drifted),
+                        "aggregate_deviation": dr.aggregate,
+                        "profile_match": dr.profile_match}
+    EVENTS.emit("explain.report",
+                message=f"explain {rep['model']} (served"
+                        f"{', with drift' if rows else ''})",
+                model=rep["model"], device=rep["device"],
+                degraded=rep.get("degraded", False),
+                n_drift_units=len(rows or []))
+    return rep
+
+
 def validate_report(rep: dict) -> dict:
     """Assert the stable schema surface; returns ``rep`` for chaining.
 

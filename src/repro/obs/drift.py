@@ -153,14 +153,13 @@ class DriftProfiler:
         self._units: list | None = None     # resolved lazily
 
     @classmethod
-    def from_session(cls, session, **kw):
-        """Build from a runtime ``Session`` (its graph, quant map, artifact,
-        device, and resolved profile)."""
-        profile = kw.pop("profile", None) or session.profile
+    def from_session(cls, session, artifact, profile, **kw):
+        """Build for a runtime ``Session`` (its graph, quant map and device)
+        serving ``artifact``, judged against ``profile``."""
         if profile is None:
-            raise ValueError("session has no device profile; pass profile=")
-        return cls(session.graph, session.qm, session.artifact,
-                   session.device, profile, **kw)
+            raise ValueError("drift needs a device profile to judge against")
+        return cls(session.graph, session.qm, artifact, session.device,
+                   profile, **kw)
 
     # ------------------------------------------------------------ unit setup
     def _resolve_units(self) -> list:

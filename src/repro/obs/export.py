@@ -319,8 +319,8 @@ class ObsHTTPServer:
         self.events = events if events is not None else EVENTS
         self.tracer = tracer if tracer is not None else obs_trace.TRACER
         # model name -> zero-arg callable returning a JSON-safe CompileReport
-        # (``Session.explain`` bound by the serving layer; lazy so each scrape
-        # sees the CURRENT report — after a hot-swap the route follows)
+        # (``explain.live_report`` bound by the serving layer; lazy so each
+        # scrape sees the CURRENT report — after a hot-swap the route follows)
         self._explain: dict = dict(explain or {})
         plane = self
 
@@ -389,8 +389,9 @@ class ObsHTTPServer:
     def add_explain(self, model: str, provider) -> None:
         """Register (or replace) the ``/explain/<model>`` provider: a
         zero-arg callable returning the model's current CompileReport dict
-        (typically ``session.explain`` — re-evaluated per scrape, so a
-        hot-swapped artifact explains its new plan immediately)."""
+        (typically a closure over ``explain.live_report`` — re-evaluated
+        per scrape, so a hot-swapped artifact explains its new plan
+        immediately)."""
         self._explain[model] = provider
 
     def remove_explain(self, model: str) -> None:

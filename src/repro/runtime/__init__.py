@@ -1,18 +1,20 @@
-"""Runtime supporter (paper §1, §3.2): serve compiled artifacts end to end.
+"""Runtime supporter (paper §1, §3.2): serve lowered programs end to end.
 
 DNNVM is "an integration of optimizers ..., an assembler, a runtime supporter
 and a validation environment"; this package is the runtime supporter — the
 host-side layer that feeds the accelerator:
 
-* :class:`Session`         — owns one compiled model (artifact via PlanCache,
-                             executor, memory plan); ``run`` / ``run_batch``
-                             / ``launch`` (dispatch without waiting).
+* :class:`Session`         — owns the executor of one lowered model (a
+                             searched strategy, or a loaded artifact's
+                             stored program); ``run`` / ``run_batch`` /
+                             ``launch`` (dispatch without waiting).
 * :class:`DynamicBatcher`  — async request queue with max-batch / max-latency
                              knobs; a worker dispatches queued images as one
                              batched launch while the previous batch runs,
                              a collector resolves each batch's futures.
 * :class:`Server`          — Session + batcher + latency/batch metrics.
-* :class:`MultiServer`     — many models on one device: DDR partitioning,
+* :class:`MultiServer`     — many zoo artifacts on one device: DDR
+                             partitioning by each artifact's planned peak,
                              per-tenant SLO classes, admission control.
 * :class:`Fleet`           — N data-parallel Session replicas across
                              ``jax.devices()``: health-driven failover,
@@ -20,10 +22,11 @@ host-side layer that feeds the accelerator:
 * :class:`ChaosInjector`   — deterministic fault injection (kill / poison /
                              hang / slow) on fleet replicas, for the chaos
                              gate.
-* :func:`pipeline_report`  — engine-level cross-request schedule: the
-                             artifact's addressed instruction stream,
-                             software-pipelined across requests on the time
-                             wheel and audited by the memory-hazard oracle.
+* :func:`pipeline_report`  — the object file's engine-level cross-request
+                             schedule: the artifact's addressed instruction
+                             stream, software-pipelined across requests on
+                             the time wheel and audited by the memory-hazard
+                             oracle.
 """
 from repro.runtime.batching import BatcherClosed, DynamicBatcher
 from repro.runtime.chaos import ChaosError, ChaosInjector

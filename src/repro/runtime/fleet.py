@@ -4,10 +4,11 @@ failover.
 The runtime so far assumed one accelerator that never fails.  A production
 serving plane must keep answering when a replica dies mid-batch, so the
 :class:`Fleet` places N data-parallel :class:`~repro.runtime.session.Session`
-replicas across ``jax.devices()`` (forced-host devices in CI; each replica's
-plan cache seeded from ONE shared :class:`~repro.asm.artifact.
-CompiledArtifact`, so the fleet compiles nothing) and puts a failover router
-in front of their per-replica :class:`~repro.runtime.server.Server`s:
+replicas across ``jax.devices()`` (forced-host devices in CI; every replica
+opens on ONE shared :class:`~repro.asm.artifact.CompiledArtifact` and
+dispatches its stored program, so the fleet compiles nothing) and puts a
+failover router in front of their per-replica
+:class:`~repro.runtime.server.Server`s:
 
 * **routing** — each request goes to the active replica with the smallest
   expected drain time, ``(queue depth + 1) x recent p99`` (cold replicas tie
@@ -123,13 +124,12 @@ class Fleet:
                  probe_interval_s: float = 0.25,
                  probe_timeout_s: float = 5.0,
                  max_queue_per_replica: int = 64,
-                 session_kw: dict | None = None,
                  server_kw: dict | None = None,
                  monitor: HeartbeatMonitor | None = None,
                  flight=None, events=None, registry=None,
                  clock=time.monotonic):
         """``artifact`` is the one shared compiled model every replica serves
-        (each replica's plan cache is seeded from it — no recompilation).
+        (each replica dispatches its stored program — no recompilation).
         ``n_replicas`` defaults to ``len(devices)``; with fewer devices than
         replicas, placement wraps round-robin (multi-session-per-device).
         ``max_retries`` bounds RE-dispatches per request (so a request runs
@@ -187,14 +187,12 @@ class Fleet:
         self._m_active = self._registry.gauge("fleet.active_replicas")
         self._m_pending = self._registry.gauge("fleet.pending")
 
-        session_kw = dict(session_kw or {})
         server_kw = dict(server_kw or {})
         for i in range(self.n_replicas):
             rid = f"r{i}"
             dev = self.devices[i % len(self.devices)]
-            session = Session.from_artifact(
-                artifact, backend=backend,
-                cache=_fresh_plan_cache(), placement=dev, **session_kw)
+            session = Session.from_artifact(artifact, backend=backend,
+                                            placement=dev)
             server = Server(session,
                             labels={"replica": rid},
                             observers=[self._observer(rid),
@@ -655,8 +653,3 @@ class Fleet:
 
     def __exit__(self, *exc):
         self.close()
-
-
-def _fresh_plan_cache():
-    from repro.asm import PlanCache
-    return PlanCache()

@@ -1,12 +1,14 @@
 """Multi-tenant serving: many compiled models, one device, one front door.
 
 The zoo makes artifacts cheap to hold; this module makes them cheap to
-*serve together*.  A :class:`MultiServer` routes per-model request streams to
-per-model :class:`~repro.runtime.session.Session`/:class:`~repro.runtime.
-server.Server` pairs that share one device:
+*serve together*.  A :class:`MultiServer` admits the zoo's object files
+(``stages.Compiled`` or ``asm.CompiledArtifact``) and routes per-model
+request streams to per-model :class:`~repro.runtime.session.Session`/
+:class:`~repro.runtime.server.Server` pairs that share one device:
 
 * **DDR partitioning** — every resident model's memory plan claims a
-  disjoint DDR region (base offset + its planned ``peak_ddr_bytes``);
+  disjoint DDR region (base offset + the artifact's planned
+  ``peak_ddr_bytes``);
   ``add_model`` refuses a model whose footprint would overflow the device's
   (or a configured) budget, so co-residency is checked at admission time,
   not discovered as corruption at run time;
@@ -40,13 +42,10 @@ class MultiServer:
 
     def __init__(self, *, ddr_budget_bytes: int | None = None,
                  max_queue: int = 256, slo_classes: dict | None = None,
-                 plan_cache_max_entries: int | None = None,
                  flight=None, events=None, burn_kw: dict | None = None):
         """``ddr_budget_bytes`` caps the summed planned footprints of all
         resident models (default: the shared device's ``ddr_bytes``).
         ``max_queue`` is the default per-tenant admission bound.
-        ``plan_cache_max_entries`` rebounds the shared ``asm.PLAN_CACHE`` —
-        a many-model host sets it to cap resident compiled artifacts.
 
         The host owns one observability plane for all tenants: ``flight`` is
         the shared :class:`~repro.obs.flight.FlightRecorder` (one is created
@@ -70,31 +69,32 @@ class MultiServer:
         self.flight = flight if flight is not None else FlightRecorder()
         self._burn_kw = dict(burn_kw) if burn_kw else {}
         self._obs_http = None
-        if plan_cache_max_entries is not None:
-            from repro import asm
-            asm.PLAN_CACHE.max_entries = plan_cache_max_entries
 
     # ---------------------------------------------------------------- models
-    def _as_session(self, model, backend, session_kw):
-        """Accept a stages.Compiled, a CompiledArtifact, or a live Session."""
+    @staticmethod
+    def _artifact_of(model):
+        """The object file of a stages.Compiled or a CompiledArtifact."""
         from repro.asm.artifact import CompiledArtifact
-        from repro.runtime.session import Session
 
-        if isinstance(model, Session):
-            return model
-        if isinstance(model, CompiledArtifact):
-            return Session.from_artifact(model, backend=backend, **session_kw)
-        art = getattr(model, "artifact", None)      # stages.Compiled
-        if isinstance(art, CompiledArtifact):
-            return Session.from_artifact(art, backend=backend, **session_kw)
-        raise TypeError(f"cannot serve {type(model).__name__}; expected a "
-                        "Session, CompiledArtifact, or stages.Compiled")
+        art = getattr(model, "artifact", model)     # stages.Compiled
+        if not isinstance(art, CompiledArtifact):
+            raise TypeError(f"cannot serve {type(model).__name__}; expected "
+                            "a CompiledArtifact or stages.Compiled")
+        return art
+
+    def _explain(self, name: str):
+        """``/explain/<name>``: the tenant's compile report, joined with its
+        live drift samples on every scrape."""
+        from repro.explain import live_report
+        m = self._models[name]
+        return lambda: live_report(m["artifact"], m["session"].drift)
 
     def add_model(self, name: str, model, *, slo: str = "best_effort",
                   target_p99_ms: float | None = None,
                   max_queue: int | None = None, backend: str = "ref",
                   session_kw: dict | None = None, **server_kw):
-        """Admit one model under ``name`` and start serving it.
+        """Admit one model (a ``stages.Compiled`` or ``CompiledArtifact``)
+        under ``name`` and start serving it.
 
         ``slo`` picks the tenant's SLO class (an explicit ``target_p99_ms``
         overrides the class target).  Raises :class:`MemoryError` when the
@@ -105,7 +105,11 @@ class MultiServer:
         if slo not in self.slo_classes:
             raise ValueError(f"unknown SLO class {slo!r}; have "
                              f"{sorted(self.slo_classes)}")
-        session = self._as_session(model, backend, session_kw or {})
+        from repro.runtime.session import Session
+
+        art = self._artifact_of(model)
+        session = Session.from_artifact(art, backend=backend,
+                                        **(session_kw or {}))
         if self._device is None:
             self._device = session.device
         elif session.device.name != self._device.name:
@@ -115,7 +119,7 @@ class MultiServer:
 
         budget = self.ddr_budget_bytes or self._device.ddr_bytes
         used = sum(m["ddr_bytes"] for m in self._models.values())
-        need = int(session.artifact.peak_ddr_bytes)
+        need = int(art.peak_ddr_bytes)
         if used + need > budget:
             raise MemoryError(
                 f"model {name!r} needs {need} B of DDR but only "
@@ -147,8 +151,8 @@ class MultiServer:
                                **server_kw)
         self.flight.set_context(name, slo_class=slo)
         self._models[name] = {
-            "session": session, "server": server, "slo": slo,
-            "burn": burn,
+            "session": session, "artifact": art, "server": server,
+            "slo": slo, "burn": burn,
             "ddr_base": used, "ddr_bytes": need,
             "max_queue": max_queue if max_queue is not None
             else self.max_queue,
@@ -158,7 +162,7 @@ class MultiServer:
                                   f"({need} B DDR, class {slo})",
                           ddr_bytes=need, ddr_base=used)
         if self._obs_http is not None:
-            self._obs_http.add_explain(name, session.explain)
+            self._obs_http.add_explain(name, self._explain(name))
         return server
 
     def remove_model(self, name: str, wait: bool = True) -> None:
@@ -179,15 +183,18 @@ class MultiServer:
 
     def attach_drift(self, name: str, **kw):
         """Attach a per-tenant :class:`~repro.obs.drift.DriftProfiler` to
-        ``name``'s session, labelled ``{model: name}`` so its gauges land
-        next to the tenant's serve metrics on the scrape endpoint.  The
+        ``name``'s session, judged against ``kw["profile"]`` (a
+        ``tune.DeviceProfile``) and labelled ``{model: name}`` so its gauges
+        land next to the tenant's serve metrics on the scrape endpoint.  The
         flight recorder then stamps the tenant's records with the latest
         drift summary.  Returns the profiler (``prepare()`` it before a
         timed window)."""
         from repro.obs.drift import DriftProfiler
-        session = self._models[name]["session"]
+        m = self._models[name]
+        session = m["session"]
         kw.setdefault("labels", {"model": name})
-        prof = DriftProfiler.from_session(session, **kw)
+        prof = DriftProfiler.from_session(session, m["artifact"],
+                                          kw.pop("profile", None), **kw)
         session.attach_drift(prof)
         return prof
 
@@ -262,8 +269,8 @@ class MultiServer:
                 host=host, port=port)
         # (re)register every resident tenant's explain provider — models
         # admitted after the endpoint came up are picked up on the next call
-        for name, m in self._models.items():
-            self._obs_http.add_explain(name, m["session"].explain)
+        for name in self._models:
+            self._obs_http.add_explain(name, self._explain(name))
         return self._obs_http
 
     def close(self, wait: bool = True) -> None:

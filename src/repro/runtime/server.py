@@ -218,17 +218,14 @@ class Server:
     def serve_metrics(self, host: str = "127.0.0.1", port: int = 0):
         """Mount the OpenMetrics scrape endpoint (plus /flight, /events,
         /snapshot, /explain) for this server's plane; returns the running
-        :class:`~repro.obs.export.ObsHTTPServer` (closed with the server)."""
+        :class:`~repro.obs.export.ObsHTTPServer` (closed with the server).
+        A caller holding the served artifact registers its
+        ``/explain/<model>`` with ``add_explain``."""
         from repro.obs.export import ObsHTTPServer
         if self._obs_http is None:
             self._obs_http = ObsHTTPServer(
                 self._registry, flight=self.flight, events=self._events,
                 host=host, port=port)
-            # /explain/<model>: the served session's compile report, joined
-            # with its live drift samples on every scrape
-            model = ((self.labels or {}).get("model")
-                     or self.session.graph.name)
-            self._obs_http.add_explain(model, self.session.explain)
         return self._obs_http
 
     def close(self, wait: bool = True, timeout_s: float | None = None) -> None:

@@ -9,7 +9,7 @@ and the on-disk model zoo (``repro.zoo``) can content-address object files.
     from repro.stages import wrap, compile_model
 
     co = compile_model(g, qm, ZU2, profile=prof)     # all four stages
-    sess = co.session(backend="pallas")
+    art = co.artifact                                # the object file
 
     w  = wrap(g, qm, ZU2)                            # or stage by stage
     lo = w.lower(profile=prof)                       # search + lower

@@ -243,17 +243,13 @@ class Planned:
 # ------------------------------------------------------------------ compiled
 @dataclasses.dataclass
 class Compiled:
-    """Stage 4: the DNNVM object file, ready to serve or to shelve."""
+    """Stage 4: the DNNVM object file, ready to shelve or to serve
+    (``runtime.Session.from_artifact(compiled.artifact)``)."""
     artifact: _art.CompiledArtifact
     key: str                         # content address (the zoo's key)
     stage_keys: dict                 # stage name -> content hash
     planned: Planned | None = None   # None when reopened from an object file
     _cache: object = dataclasses.field(default=None, repr=False, compare=False)
-
-    def session(self, backend: str = "ref", **kw):
-        """Open a runtime ``Session`` on the artifact (plan cache seeded,
-        no recompilation)."""
-        return self.artifact.session(backend=backend, **kw)
 
     def save(self, path: str) -> None:
         _art.save_artifact(self.artifact, path)

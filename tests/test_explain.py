@@ -314,9 +314,11 @@ def test_fallback_reason_counters(compiled):
 
 
 def test_session_explain_joins_drift(compiled):
+    """``live_report`` joins the artifact's report with the drift samples of
+    the session serving it."""
     from repro import asm
     from repro.core.cost import SimulatorEvaluator
-    from repro.explain import validate_report
+    from repro.explain import live_report, render_report, validate_report
     from repro.obs.drift import DriftProfiler
     from repro.obs.events import EVENTS
     from repro.obs.metrics import MetricsRegistry
@@ -328,15 +330,15 @@ def test_session_explain_joins_drift(compiled):
     sim = SimulatorEvaluator(g, dev)
     prof = calibrate(g, qm, dev, measure_fn=lambda grp: sim(grp),
                      features="analytic").profile
-    sess = Session(g, s, dev, qm, backend="pallas", cache=asm.PlanCache(),
-                   profile=prof)
-    rep = sess.explain()
+    art = asm.compile_strategy(g, s, dev, qm=qm, profile=prof)
+    sess = Session.from_artifact(art, backend="pallas")
+    rep = live_report(art, sess.drift)
     validate_report(rep)
     assert "drift" not in rep                       # no profiler attached
 
     # an undrifted world: measurements ARE the profile's own predictions
     dp = DriftProfiler.from_session(
-        sess, every=1, registry=MetricsRegistry(),
+        sess, art, prof, every=1, registry=MetricsRegistry(),
         measure_fn=lambda item: predict_item_seconds(prof, g, dev, item))
     sess.attach_drift(dp)
     dp.sample()
@@ -344,7 +346,7 @@ def test_session_explain_joins_drift(compiled):
     sub = seen.append
     EVENTS.subscribe(sub)
     try:
-        rep = sess.explain()
+        rep = live_report(art, sess.drift)
     finally:
         EVENTS.unsubscribe(sub)
     assert rep["drift"]["units"]
@@ -357,7 +359,7 @@ def test_session_explain_joins_drift(compiled):
     assert rep["drift"]["drifted"] is False
     assert rep["drift"]["profile_match"] is True
     assert any(e.kind == "explain.report" for e in seen)
-    text = sess.explain(render=True)
+    text = render_report(rep, drift=rep["drift"]["units"])
     assert "live drift" in text
 
 

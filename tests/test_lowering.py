@@ -240,9 +240,10 @@ def test_artifact_carries_program_and_round_trips(rng, tmp_path):
     loaded = asm.load_artifact(path)
     assert lower.program_to_json(loaded.program) == \
         lower.program_to_json(art.program)
-    # the loaded artifact's executor dispatches the STORED program (no
+    # a session on the loaded artifact dispatches the STORED program (no
     # re-lowering, no graph inspection: the artifact is self-contained)
-    ex = loaded.executor(backend="pallas")
+    from repro.runtime import Session
+    ex = Session.from_artifact(loaded, backend="pallas").executor
     assert ex.program is loaded.program
 
     rep = validate.artifact_round_trip(g, qm, xq, s, ZU2,

@@ -1,5 +1,5 @@
 """Multi-tenant serving: routing bit-exactness, DDR partitioning, admission
-control, and the bounded shared plan cache (ISSUE 7 satellites)."""
+control, and the bounded shared plan cache."""
 import numpy as np
 import pytest
 
@@ -11,16 +11,25 @@ from repro.runtime import AdmissionError, MultiServer, Session
 from tests.conftest import make_toy_resnet_graph, toy_params
 
 
-def _model(seed, cache=None):
-    """One compiled toy model; ``seed`` differentiates the weights."""
+def _inputs(seed):
+    """Graph, quantized model and strategy of one toy model; ``seed``
+    differentiates the weights."""
     g = make_toy_resnet_graph()
     params = toy_params(g, seed=seed)
     x = np.random.default_rng(seed).standard_normal(
         g.shape("data")).astype(np.float32)
     qm = quantize.calibrate(g, params, x, executor.run_float)
-    s = pathsearch.search(g, ZU2)
-    return Session(g, s, ZU2, qm,
-                   cache=cache if cache is not None else asm.PlanCache())
+    return g, qm, pathsearch.search(g, ZU2)
+
+
+def _model(seed):
+    """One compiled toy model: the object file a tenant is admitted with."""
+    g, qm, s = _inputs(seed)
+    return asm.compile_strategy(g, s, ZU2, qm=qm)
+
+
+def _request_shape(art):
+    return tuple(art.rebuild_graph().shape("data")[1:])
 
 
 @pytest.fixture(scope="module")
@@ -33,16 +42,16 @@ def test_routing_is_bit_exact_per_tenant(two_models):
     """Interleaved streams for two co-resident models must produce exactly
     what each model's own session produces in isolation."""
     sa, sb = two_models
-    g = sa.graph
+    alone = {"a": Session.from_artifact(sa), "b": Session.from_artifact(sb)}
     rng = np.random.default_rng(3)
-    xs = rng.integers(-128, 127, (6,) + tuple(g.shape("data")[1:]), np.int8)
+    xs = rng.integers(-128, 127, (6,) + _request_shape(sa), np.int8)
     with MultiServer() as ms:
         ms.add_model("a", sa, slo="gold", max_latency_s=1e-4, warmup=False)
         ms.add_model("b", sb, slo="silver", max_latency_s=1e-4, warmup=False)
         futs = [(name, x, ms.submit(name, x))
                 for x in xs for name in ("a", "b")]
         for name, x, fut in futs:
-            want = (sa if name == "a" else sb).run(x)
+            want = alone[name].run(x)
             got = fut.result(timeout=30)
             for k in want:
                 np.testing.assert_array_equal(got[k], want[k])
@@ -68,7 +77,7 @@ def test_ddr_partition_is_disjoint_and_bounded(two_models):
 
 def test_add_model_refused_when_ddr_budget_exhausted(two_models):
     sa, sb = two_models
-    budget = int(sa.artifact.peak_ddr_bytes * 1.5)     # fits one, not two
+    budget = int(sa.peak_ddr_bytes * 1.5)              # fits one, not two
     with MultiServer(ddr_budget_bytes=budget) as ms:
         ms.add_model("a", sa, warmup=False)
         with pytest.raises(MemoryError, match="DDR"):
@@ -93,8 +102,7 @@ def test_device_and_name_conflicts_rejected(two_models):
 # --------------------------------------------------------- admission control
 def test_admission_control_sheds_load(two_models):
     sa, _ = two_models
-    g = sa.graph
-    x = np.zeros(tuple(g.shape("data")[1:]), np.int8)
+    x = np.zeros(_request_shape(sa), np.int8)
     with MultiServer() as ms:
         ms.add_model("a", sa, max_queue=0, warmup=False)
         with pytest.raises(AdmissionError):
@@ -110,8 +118,7 @@ def test_rejection_feeds_events_and_flight_recorder(two_models):
     from repro.obs.flight import FlightRecorder
 
     sa, _ = two_models
-    g = sa.graph
-    x = np.zeros(tuple(g.shape("data")[1:]), np.int8)
+    x = np.zeros(_request_shape(sa), np.int8)
     events = EventLog()
     flight = FlightRecorder(capacity=16, events=events)
     with MultiServer(flight=flight, events=events) as ms:
@@ -125,7 +132,8 @@ def test_rejection_feeds_events_and_flight_recorder(two_models):
     dump = flight.dumps()[-1]
     assert dump["reason"] == "admission_rejection"
     assert dump["context"]["a"]["slo_class"] == "best_effort"
-    assert dump["context"]["a"]["tiles"] == sa.tile_summary()
+    assert dump["context"]["a"]["tiles"] == \
+        Session.from_artifact(sa).tile_summary()
 
 
 def test_stats_use_label_index_and_expose_burn(two_models):
@@ -133,8 +141,7 @@ def test_stats_use_label_index_and_expose_burn(two_models):
     ``MetricsRegistry.labelled`` (no hand-formatted name lookups) and carry
     live burn rates for SLO-targeted tenants."""
     sa, sb = two_models
-    g = sa.graph
-    x = np.zeros(tuple(g.shape("data")[1:]), np.int8)
+    x = np.zeros(_request_shape(sa), np.int8)
     with MultiServer(burn_kw=dict(fast_window_s=1.0, slow_window_s=2.0,
                                   min_samples=4)) as ms:
         ms.add_model("a", sa, slo="gold", warmup=False)
@@ -161,8 +168,7 @@ def test_gold_slo_violation_alerts_and_dumps(two_models):
     from repro.obs.flight import FlightRecorder
 
     sa, _ = two_models
-    g = sa.graph
-    x = np.zeros(tuple(g.shape("data")[1:]), np.int8)
+    x = np.zeros(_request_shape(sa), np.int8)
     events = EventLog()
     flight = FlightRecorder(capacity=64, events=events)
     with MultiServer(flight=flight, events=events,
@@ -184,39 +190,23 @@ def test_gold_slo_violation_alerts_and_dumps(two_models):
 
 # -------------------------------------------------- bounded shared plan cache
 def test_plan_cache_lru_eviction_across_three_models():
-    """A shared plan cache bounded to 2 entries serving 3 models must evict
-    LRU artifacts and count the evictions into the metrics registry."""
+    """A plan cache bounded to 2 entries compiling 3 models must evict LRU
+    artifacts and count the evictions into the metrics registry."""
     before = (REGISTRY.get("plan_cache.evictions").value
               if REGISTRY.get("plan_cache.evictions") else 0.0)
     cache = asm.PlanCache(max_entries=2)
-    sessions = [_model(seed, cache=cache) for seed in (0, 1, 2)]
+    models = [_inputs(seed) for seed in (0, 1, 2)]
+    arts = [cache.get_or_compile(g, s, ZU2, qm=qm)[0] for g, qm, s in models]
     assert len(cache) == 2
     assert cache.evictions == 1
     assert REGISTRY.get("plan_cache.evictions").value == before + 1
-    # model 0 was evicted (LRU): rebuilding it is a miss; model 2 is a hit
-    s2 = Session(sessions[2].graph, sessions[2].artifact, ZU2,
-                 sessions[2].qm, cache=cache)
-    assert s2.cache_hit
+    # model 0 was evicted (LRU): compiling it again is a miss; model 2 hits
+    g, qm, _ = models[2]
+    art, hit = cache.get_or_compile(g, arts[2], ZU2, qm=qm)
+    assert hit and art is arts[2]
     misses = cache.misses
-    s0 = Session(sessions[0].graph, sessions[0].artifact, ZU2,
-                 sessions[0].qm, cache=cache)
-    assert not s0.cache_hit and cache.misses == misses + 1
-
-
-def test_session_exposes_cache_max_entries():
-    cache = asm.PlanCache()
-    s = _model(0)
-    sess = Session(s.graph, s.artifact, ZU2, s.qm, cache=cache,
-                   cache_max_entries=3)
-    assert cache.max_entries == 3
+    g, qm, _ = models[0]
+    _, hit = cache.get_or_compile(g, arts[0], ZU2, qm=qm)
+    assert not hit and cache.misses == misses + 1
     with pytest.raises(ValueError):
         cache.max_entries = 0
-
-
-def test_multiserver_rebounds_shared_plan_cache():
-    old = asm.PLAN_CACHE.max_entries
-    try:
-        MultiServer(plan_cache_max_entries=5)
-        assert asm.PLAN_CACHE.max_entries == 5
-    finally:
-        asm.PLAN_CACHE.max_entries = old

@@ -243,13 +243,14 @@ def test_server_stats_carry_split_percentiles(toy_session):
 
 # -------------------------------------------------------------------- drift
 @pytest.fixture(scope="module")
-def toy_session():
+def toy_tuned():
+    """A profile-guided toy compile: (artifact, the profile it was planned
+    under)."""
     from tests.conftest import make_toy_resnet_graph, toy_params
     from repro import asm
     from repro.core import executor, pathsearch, quantize
     from repro.core.cost import SimulatorEvaluator
     from repro.hw import ZU2
-    from repro.runtime import Session
     from repro.tune import CalibratedEvaluator, calibrate
 
     g = make_toy_resnet_graph()
@@ -262,24 +263,34 @@ def toy_session():
                     features="analytic")
     p = res.profile
     s = pathsearch.search(g, ZU2, evaluator=CalibratedEvaluator(g, ZU2, p))
-    return Session(g, s, ZU2, qm, backend="pallas", cache=asm.PlanCache(),
-                   profile=p)
+    return asm.compile_strategy(g, s, ZU2, qm=qm, profile=p), p
 
 
-def _prediction_fn(session):
+@pytest.fixture(scope="module")
+def toy_session(toy_tuned):
+    from repro.runtime import Session
+    art, _ = toy_tuned
+    return Session.from_artifact(art, backend="pallas")
+
+
+def _prediction_fn(session, p):
     """measure_fn that returns exactly the profile's own predictions — an
     undrifted world, deterministic."""
     from repro.tune.evaluator import predict_item_seconds
 
-    p = session.profile
     return lambda item: predict_item_seconds(p, session.graph,
                                              session.device, item)
 
 
-def test_drift_unperturbed_within_band(toy_session):
-    dp = DriftProfiler.from_session(toy_session, every=1,
-                                    measure_fn=_prediction_fn(toy_session),
-                                    registry=MetricsRegistry())
+def _drift(session, tuned, **kw):
+    art, p = tuned
+    return DriftProfiler.from_session(session, art, p,
+                                      registry=MetricsRegistry(), **kw)
+
+
+def test_drift_unperturbed_within_band(toy_session, toy_tuned):
+    dp = _drift(toy_session, toy_tuned, every=1,
+                measure_fn=_prediction_fn(toy_session, toy_tuned[1]))
     dp.sample()
     rep = dp.report()
     assert rep.units and not rep.skipped
@@ -290,13 +301,12 @@ def test_drift_unperturbed_within_band(toy_session):
     json.dumps(rep.to_json())
 
 
-def test_drift_perturbed_profile_flagged(toy_session):
-    p2 = dataclasses.replace(
-        toy_session.profile,
-        coef=tuple(2 * c for c in toy_session.profile.coef))
-    dp = DriftProfiler(toy_session.graph, toy_session.qm,
-                       toy_session.artifact, toy_session.device, p2, every=1,
-                       measure_fn=_prediction_fn(toy_session),
+def test_drift_perturbed_profile_flagged(toy_session, toy_tuned):
+    art, p = toy_tuned
+    p2 = dataclasses.replace(p, coef=tuple(2 * c for c in p.coef))
+    dp = DriftProfiler(toy_session.graph, toy_session.qm, art,
+                       toy_session.device, p2, every=1,
+                       measure_fn=_prediction_fn(toy_session, p),
                        registry=MetricsRegistry())
     dp.sample()
     rep = dp.report()
@@ -307,16 +317,14 @@ def test_drift_perturbed_profile_flagged(toy_session):
     assert rep.drifted
 
 
-def test_drift_sampling_cadence(toy_session):
+def test_drift_sampling_cadence(toy_session, toy_tuned):
     calls = []
 
     def fake_measure(item):
         calls.append(item)
         return 1e-3
 
-    dp = DriftProfiler.from_session(toy_session, every=4,
-                                    measure_fn=fake_measure,
-                                    registry=MetricsRegistry())
+    dp = _drift(toy_session, toy_tuned, every=4, measure_fn=fake_measure)
     n_units = len(dp._resolve_units())
     fired = [dp.observe_launch() for _ in range(8)]
     assert fired == [False, False, False, True] * 2       # every 4th
@@ -324,17 +332,16 @@ def test_drift_sampling_cadence(toy_session):
     assert dp.n_observed == 8 and dp.n_sampled == 2
 
 
-def test_drift_gauges_are_labelled_per_model(toy_session):
-    """ISSUE 8 satellite: drift reports land as per-model gauges
+def test_drift_gauges_are_labelled_per_model(toy_session, toy_tuned):
+    """Drift reports land as per-model gauges
     (``drift.median_deviation{model=}``, ``drift.tripped{model=}``) and the
     first False->True transition emits one ``drift.trip`` event."""
     reg = MetricsRegistry()
-    p2 = dataclasses.replace(
-        toy_session.profile,
-        coef=tuple(2 * c for c in toy_session.profile.coef))
-    dp = DriftProfiler(toy_session.graph, toy_session.qm,
-                       toy_session.artifact, toy_session.device, p2, every=1,
-                       measure_fn=_prediction_fn(toy_session),
+    art, p = toy_tuned
+    p2 = dataclasses.replace(p, coef=tuple(2 * c for c in p.coef))
+    dp = DriftProfiler(toy_session.graph, toy_session.qm, art,
+                       toy_session.device, p2, every=1,
+                       measure_fn=_prediction_fn(toy_session, p),
                        registry=reg, labels={"model": "toy"})
     # the trip event goes through the shared log; watch it via a subscriber
     from repro.obs.events import EVENTS
@@ -360,16 +367,15 @@ def test_drift_gauges_are_labelled_per_model(toy_session):
 
 def test_session_tile_summary_names_every_lowered_unit(toy_session):
     tiles = toy_session.tile_summary()
-    assert tiles and len(tiles) == len(toy_session.artifact.program.items)
+    assert tiles and len(tiles) == len(toy_session.executor.program.items)
     for t in tiles:
         assert set(t) == {"nodes", "kind", "tile"}
         assert t["kind"] in ("chain", "horizontal", "fallback")
 
 
-def test_drift_attaches_to_session_serving(toy_session):
-    dp = DriftProfiler.from_session(toy_session, every=2,
-                                    measure_fn=_prediction_fn(toy_session),
-                                    registry=MetricsRegistry())
+def test_drift_attaches_to_session_serving(toy_session, toy_tuned):
+    dp = _drift(toy_session, toy_tuned, every=2,
+                measure_fn=_prediction_fn(toy_session, toy_tuned[1]))
     toy_session.attach_drift(dp)
     try:
         rng = np.random.default_rng(1)
@@ -383,31 +389,37 @@ def test_drift_attaches_to_session_serving(toy_session):
     assert not dp.report().drifted
 
 
-def test_from_artifact_keeps_resolved_profile(toy_session, tmp_path):
-    """Regression: loading an artifact under a profile must hand the profile
-    to the constructed session (profile-guided ddr_slots auto-selection and
-    session-side provenance), still without recompiling."""
+def test_from_artifact_keeps_resolved_profile(toy_tuned, tmp_path):
+    """A loaded artifact keeps the hash of the profile it was planned under;
+    that profile drives the artifact's ddr-slot auto-selection, and a drift
+    profiler judges a session on the loaded artifact against it, with no
+    recompile."""
     from repro import asm
+    from repro.hw import ZU2
     from repro.runtime import Session
+    from repro.runtime.schedule import pipeline_report
 
-    p = toy_session.profile
+    art, p = toy_tuned
     path = str(tmp_path / "tuned.npz")
-    asm.save_artifact(toy_session.artifact, path)
+    asm.save_artifact(art, path)
     loaded = asm.load_artifact(path)
+    assert loaded.profile_hash == p.hash()
 
     cache = asm.PlanCache()
-    sess = Session.from_artifact(loaded, cache=cache, profile=p)
-    assert sess.cache_hit and cache.misses == 0           # no recompile
-    assert sess.profile == p                              # profile kept
-    st = sess.stats()
-    assert st["profile_hash"] == p.hash()
-    assert st["session_profile_hash"] == p.hash()
-    # the kept profile now drives ddr-slot auto-selection
-    assert sess.pipeline_report(2, ddr_slots=None).ddr_slots_source == \
-        "profile"
-    # and a DriftProfiler can be built straight from the loaded session
-    DriftProfiler.from_session(sess, measure_fn=lambda item: 1e-3,
-                               registry=MetricsRegistry())
+    g2, qm2 = loaded.rebuild_graph(), loaded.quantized_model()
+    cache.put(g2, loaded, ZU2, loaded, qm=qm2, profile=p)
+    _, hit = cache.get_or_compile(g2, loaded, ZU2, qm=qm2, profile=p)
+    assert hit and cache.misses == 0                      # no recompile
+    # the profile drives ddr-slot auto-selection
+    assert pipeline_report(loaded, 2, ddr_slots=None,
+                           profile=p).ddr_slots_source == "profile"
+    # and a DriftProfiler can be built for a session on the loaded artifact
+    sess = Session.from_artifact(loaded, backend="pallas")
+    dp = DriftProfiler.from_session(sess, loaded, p,
+                                    measure_fn=lambda item: 1e-3,
+                                    registry=MetricsRegistry())
+    dp.sample()
+    assert dp.report().profile_match
 
 
 def test_global_tracer_disabled_by_default():

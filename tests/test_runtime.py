@@ -1,4 +1,4 @@
-"""Runtime supporter (ISSUE 3 tentpole): plan-cached sessions, the dynamic
+"""Runtime supporter: sessions on the lowered program, the dynamic
 batching queue, batch-dim execution bit-exactness, executor input validation,
 and the hazard-audited cross-request pipeline schedule."""
 import dataclasses
@@ -69,18 +69,98 @@ def test_plan_cache_signature_stable_across_save_load(toy_compiled, tmp_path):
 
 
 def test_session_from_artifact_seeds_cache(toy_compiled, tmp_path):
+    """A loaded object file seeds a plan cache under its own key (a later
+    ``get_or_compile`` hits, compiling nothing), and a session opens on it
+    without touching any cache."""
     g, qm, s = toy_compiled
     cache = asm.PlanCache()
     art, _ = cache.get_or_compile(g, s, ZU2, qm=qm)
     path = str(tmp_path / "art.npz")
     asm.save_artifact(art, path)
     loaded = asm.load_artifact(path)
+    g2, qm2 = loaded.rebuild_graph(), loaded.quantized_model()
+    fresh = asm.PlanCache()
+    fresh.put(g2, loaded, ZU2, loaded, qm=qm2)
+    got, hit = fresh.get_or_compile(g2, loaded, ZU2, qm=qm2)
+    assert hit and got is loaded and fresh.misses == 0   # seeded
     misses_before = cache.misses
-    sess = Session.from_artifact(loaded, cache=cache)
-    assert cache.misses == misses_before      # seeded, not recompiled
-    assert sess.cache_hit
+    sess = Session.from_artifact(loaded)
+    assert cache.misses == misses_before and fresh.misses == 0
     out = sess.run(np.zeros((1,) + tuple(g.shape("data")[1:]), np.int8))
     assert set(out) == set(sess.outputs)
+
+
+# ------------------------------------ one execution model on the served path
+def _serve_bit_exact(sess, g, qm, n=2, seed=5):
+    """Serve ``n`` requests as one batch through ``Server``; each answer must
+    equal the int8 oracle's bit for bit."""
+    rng = np.random.default_rng(seed)
+    reqs = [rng.integers(-128, 128, g.shape("data")).astype(np.int8)
+            for _ in range(n)]
+    with sess.serve(max_batch=n, allowed_sizes=[n],
+                    max_latency_s=0.05) as server:
+        outs = [f.result(timeout=300)
+                for f in [server.submit(x) for x in reqs]]
+    oracle = Int8Executor(g, qm, strategy=None, backend="ref")
+    for x, got in zip(reqs, outs):
+        want = oracle(x)
+        assert set(got) == set(want)
+        for k in want:
+            np.testing.assert_array_equal(got[k], want[k])
+
+
+@pytest.mark.parametrize("backend", ["ref", "pallas"])
+def test_session_runs_no_fpga_planning(toy_compiled, monkeypatch, backend):
+    """Building and serving a session runs none of the object file's
+    planning: tiling solve, memory plan, ISA emission, hazard audit, compile
+    report or weight hashing."""
+    import repro.explain.report as report_mod
+    from repro import memory
+    from repro.asm import artifact as art_mod
+    from repro.core import isa
+
+    def refuse(*a, **k):
+        raise AssertionError("the served path planned the FPGA object file")
+
+    for mod, name in [(art_mod, "plan_strategy"), (art_mod, "plan_memory"),
+                      (art_mod, "emit_strategy"), (art_mod, "quant_signature"),
+                      (memory, "plan_memory"), (isa, "emit_strategy"),
+                      (simulator, "check"), (report_mod, "build_report")]:
+        monkeypatch.setattr(mod, name, refuse)
+    g, qm, s = toy_compiled
+    with pytest.raises(AssertionError, match="planned the FPGA"):
+        asm.compile_strategy(g, s, ZU2, qm=qm)      # the guards do bite
+    sess = Session(g, s, ZU2, qm, backend=backend)
+    assert (sess.executor.program is not None) == (backend == "pallas")
+    _serve_bit_exact(sess, g, qm)
+
+
+def test_session_serves_what_the_fpga_planner_refuses(toy_compiled):
+    """A device whose DDR cannot hold the activation peak fails the object
+    file's memory plan; the session never asks that planner, and serves."""
+    from repro.memory import MemoryPlanError
+
+    g, qm, s = toy_compiled
+    tiny = dataclasses.replace(ZU2, name="zu2-tiny-ddr", ddr_bytes=1024)
+    with pytest.raises(MemoryPlanError, match="DDR capacity"):
+        asm.compile_strategy(g, s, tiny, qm=qm)
+    sess = Session(g, s, tiny, qm, backend="pallas")
+    _serve_bit_exact(sess, g, qm, seed=6)
+
+
+def test_session_and_artifact_serve_one_program(toy_compiled):
+    """A session lowering a searched strategy, a session on the compiled
+    artifact, and the artifact's stored program are one launch list."""
+    from tests.test_kernel_vmem import _launch_list
+
+    g, qm, s = toy_compiled
+    art = asm.compile_strategy(g, s, ZU2, qm=qm)
+    live = Session(g, s, ZU2, qm, backend="pallas")
+    opened = Session.from_artifact(art, backend="pallas")
+    assert opened.executor.program is art.program        # not re-lowered
+    assert _launch_list(live.executor.program) == \
+        _launch_list(opened.executor.program) == _launch_list(art.program)
+    assert live.tile_summary() == opened.tile_summary()
 
 
 # ------------------------------------------------------- dynamic batching
@@ -324,7 +404,7 @@ def test_batcher_close_drains_in_flight_batches():
 # ------------------------------------------- batched execution bit-exactness
 def test_session_batched_run_bit_exact_vs_per_request(toy_compiled):
     g, qm, s = toy_compiled
-    sess = Session(g, s, ZU2, qm, backend="ref", cache=asm.PlanCache())
+    sess = Session(g, s, ZU2, qm, backend="ref")
     rng = np.random.default_rng(3)
     reqs = [rng.integers(-128, 128, g.shape("data")).astype(np.int8)
             for _ in range(5)]
@@ -508,20 +588,24 @@ def test_pipeline_overlaps_requests_on_vgg():
 
 
 def test_session_pipeline_report_and_stats(toy_compiled):
+    """The cross-request schedule is the artifact's; the session's stats
+    count what it served and the coverage of the program it serves."""
     g, qm, s = toy_compiled
-    sess = Session(g, s, ZU2, qm, backend="ref", cache=asm.PlanCache())
-    rep = sess.pipeline_report(3)
+    art = asm.compile_strategy(g, s, ZU2, qm=qm)
+    rep = pipeline_report(art, 3)
     assert rep.n_requests == 3
+    sess = Session.from_artifact(art, backend="ref")
     sess.run(np.zeros((1,) + tuple(g.shape("data")[1:]), np.int8))
     st = sess.stats()
     assert st["images_served"] == 1 and st["n_runs"] == 1
-    assert 0.0 <= st["fused_coverage"] <= 1.0
+    assert 0.0 < st["fused_coverage"] <= 1.0
+    assert st["fused_coverage"] == art.fused_coverage
 
 
 # ------------------------------------------------------------- end to end
 def test_server_serves_bit_exact_with_batching(toy_compiled):
     g, qm, s = toy_compiled
-    sess = Session(g, s, ZU2, qm, backend="ref", cache=asm.PlanCache())
+    sess = Session(g, s, ZU2, qm, backend="ref")
     rng = np.random.default_rng(7)
     reqs = [rng.integers(-128, 128, g.shape("data")).astype(np.int8)
             for _ in range(6)]
@@ -558,7 +642,7 @@ def _submit_concurrently(server, reqs, n_threads=4):
 
 def test_server_bit_exact_under_concurrent_submitters(toy_compiled):
     g, qm, s = toy_compiled
-    sess = Session(g, s, ZU2, qm, backend="ref", cache=asm.PlanCache())
+    sess = Session(g, s, ZU2, qm, backend="ref")
     rng = np.random.default_rng(11)
     reqs = [rng.integers(-128, 128, g.shape("data")).astype(np.int8)
             for _ in range(24)]
@@ -604,10 +688,15 @@ def test_pin_input_round_trips_through_artifact(toy_compiled, tmp_path):
     asm.save_artifact(art, path)
     loaded = asm.load_artifact(path)
     assert loaded.pin_input
-    # a session opened on the loaded artifact re-keys identically (pin_input
-    # inherited from mem_summary) and hits the seeded cache
-    sess = Session.from_artifact(loaded, cache=asm.PlanCache())
-    assert sess.cache_hit and sess.stats()["pin_input"]
+    # the loaded artifact re-keys identically (pin_input inherited from
+    # mem_summary) and hits the cache it seeds
+    g2, qm2 = loaded.rebuild_graph(), loaded.quantized_model()
+    fresh = asm.PlanCache()
+    fresh.put(g2, loaded, ZU2, loaded, qm=qm2)
+    got, hit = fresh.get_or_compile(g2, loaded, ZU2, qm=qm2)
+    assert hit and got.pin_input
+    assert fresh.key(g2, loaded, ZU2, qm2) == \
+        cache.key(g, s, ZU2, qm, pin_input=True)
 
 
 # ------------------------------------------------ latency-SLO batch sizing
@@ -618,7 +707,7 @@ def _slo_server(tmp_session, **kw):
 
 def test_server_slo_shrinks_effective_batch(toy_compiled):
     g, qm, s = toy_compiled
-    sess = Session(g, s, ZU2, qm, backend="ref", cache=asm.PlanCache())
+    sess = Session(g, s, ZU2, qm, backend="ref")
     x = np.zeros(tuple(g.shape("data")), np.int8)
     # an unreachable SLO (0 ms) must walk the cap down the allowed ladder
     with _slo_server(sess, target_p99_ms=0.0) as server:
@@ -633,7 +722,7 @@ def test_server_slo_shrinks_effective_batch(toy_compiled):
 
 def test_server_slo_regrows_when_latency_clears(toy_compiled):
     g, qm, s = toy_compiled
-    sess = Session(g, s, ZU2, qm, backend="ref", cache=asm.PlanCache())
+    sess = Session(g, s, ZU2, qm, backend="ref")
     x = np.zeros(tuple(g.shape("data")), np.int8)
     with _slo_server(sess, target_p99_ms=1e9) as server:
         server._batcher.set_max_batch(1)     # pretend a past SLO violation
@@ -648,7 +737,7 @@ def test_server_slo_regrows_when_latency_clears(toy_compiled):
 
 def test_server_without_slo_keeps_static_cap(toy_compiled):
     g, qm, s = toy_compiled
-    sess = Session(g, s, ZU2, qm, backend="ref", cache=asm.PlanCache())
+    sess = Session(g, s, ZU2, qm, backend="ref")
     x = np.zeros(tuple(g.shape("data")), np.int8)
     with _slo_server(sess) as server:
         futs = [server.submit(x) for _ in range(8)]
@@ -665,7 +754,7 @@ def test_server_slo_resizes_under_collector_appends(toy_compiled):
     import sys
 
     g, qm, s = toy_compiled
-    sess = Session(g, s, ZU2, qm, backend="ref", cache=asm.PlanCache())
+    sess = Session(g, s, ZU2, qm, backend="ref")
     x = np.zeros(tuple(g.shape("data")), np.int8)
     errors, stop = [], threading.Event()
     old = sys.getswitchinterval()

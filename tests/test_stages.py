@@ -12,6 +12,7 @@ from repro import asm
 from repro.core import executor, pathsearch, quantize
 from repro.hw import ZU2
 from repro.obs.metrics import MetricsRegistry
+from repro.runtime import Session
 from repro.stages import (Compiled, StageCache, artifact_stage_keys,
                           compile_model, wrap)
 from tests.conftest import make_toy_resnet_graph, toy_params
@@ -188,8 +189,8 @@ def test_saved_artifact_reopens_with_identical_stage_keys(toy, tmp_path):
     # and it still serves, bit-exactly
     x = np.random.default_rng(1).integers(-128, 127,
                                           g.shape("data"), np.int8)
-    got = re.session().run(x)
-    want = co.session().run(x)
+    got = Session.from_artifact(re.artifact).run(x)
+    want = Session.from_artifact(co.artifact).run(x)
     for k in want:
         np.testing.assert_array_equal(got[k], want[k])
 

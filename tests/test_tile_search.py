@@ -271,7 +271,8 @@ def test_artifact_v3_backward_compat(tmp_path):
     art3 = asm.load_artifact(p3)
     assert art3.tile_shapes == {}
     assert all(it.tile == () for it in art3.program.launches())
-    out = art3.executor(backend="pallas")(xq)
+    from repro.runtime import Session
+    out = Session.from_artifact(art3, backend="pallas").run(xq)
     ref = executor.Int8Executor(g, qm, strategy=s, backend="ref")(xq)
     for k in ref:
         np.testing.assert_array_equal(ref[k], out[k])

@@ -1,9 +1,8 @@
 """Autotuner (ISSUE 4 tentpole): device-profile round trips, calibration
 against simulator ground truth, profile-guided search determinism, and the
-artifact/session profile-hash provenance chain."""
+artifact profile-hash provenance chain."""
 import json
 import math
-import warnings
 
 import numpy as np
 import pytest
@@ -226,36 +225,6 @@ def test_strategy_carries_profile_provenance_into_cache(sim_calibration, toy):
     assert art.profile_hash == p.hash()
 
 
-def test_session_from_artifact_profile_mismatch_warns(sim_calibration, toy,
-                                                      tmp_path):
-    g, qm = toy
-    p = sim_calibration.profile
-    s = pathsearch.search(g, ZU2, evaluator=CalibratedEvaluator(g, ZU2, p))
-    art = asm.compile_strategy(g, s, ZU2, qm=qm, profile=p)
-    path = str(tmp_path / "tuned.npz")
-    asm.save_artifact(art, path)
-    loaded = asm.load_artifact(path)
-
-    # loading a profile-planned artifact without the profile warns
-    with pytest.warns(UserWarning, match="planned under device profile"):
-        sess = Session.from_artifact(loaded, cache=asm.PlanCache())
-    assert sess.cache_hit                        # still served, no recompile
-    assert sess.stats()["profile_hash"] == p.hash()
-
-    # loading under the matching profile does not
-    with warnings.catch_warnings():
-        warnings.simplefilter("error")
-        Session.from_artifact(loaded, cache=asm.PlanCache(), profile=p)
-
-    # an analytic artifact under no profile does not warn either
-    s_ana = pathsearch.search(g, ZU2)
-    art_ana = asm.compile_strategy(g, s_ana, ZU2, qm=qm)
-    asm.save_artifact(art_ana, path)
-    with warnings.catch_warnings():
-        warnings.simplefilter("error")
-        Session.from_artifact(asm.load_artifact(path), cache=asm.PlanCache())
-
-
 def test_session_compiles_under_named_profile(sim_calibration, toy, tmp_path):
     g, qm = toy
     p = sim_calibration.profile
@@ -265,10 +234,18 @@ def test_session_compiles_under_named_profile(sim_calibration, toy, tmp_path):
     old = os.environ.get("DNNVM_PROFILE_CACHE")
     os.environ["DNNVM_PROFILE_CACHE"] = cache_dir
     try:
+        from repro.stages import wrap
         s = pathsearch.search(g, ZU2)
-        sess = Session(g, s, ZU2, qm, cache=asm.PlanCache(), profile=p.name)
-        assert sess.artifact.profile_hash == p.hash()
-        assert sess.profile == p
+        lo = wrap(g, qm, ZU2, cache=None).lower(strategy=s, profile=p.name,
+                                                cache=None)
+        assert lo.profile == p                  # the name resolved
+        art = lo.plan(cache=None).compile(cache=None).artifact
+        assert art.profile_hash == p.hash()
+        assert art.meta["profile_name"] == p.name
+        # a session serves the profile-planned artifact as it is
+        sess = Session.from_artifact(art)
+        out = sess.run(np.zeros(tuple(g.shape("data")[1:]), np.int8))
+        assert set(out) == set(sess.outputs)
     finally:
         if old is None:
             os.environ.pop("DNNVM_PROFILE_CACHE", None)

@@ -9,6 +9,7 @@ from repro import asm
 from repro.core import executor, pathsearch, quantize
 from repro.hw import ZU2
 from repro.obs.metrics import MetricsRegistry
+from repro.runtime import Session
 from repro.stages import StageCache, compile_model
 from repro.zoo import ModelZoo
 from tests.conftest import make_toy_resnet_graph, toy_params
@@ -70,7 +71,8 @@ def test_compile_model_reopens_from_zoo_without_compiling(toy, tmp_path):
     # bit-exact across the reopen
     x = np.random.default_rng(2).integers(-128, 127,
                                           g.shape("data"), np.int8)
-    got, want = co2.session().run(x), co1.session().run(x)
+    got = Session.from_artifact(co2.artifact).run(x)
+    want = Session.from_artifact(co1.artifact).run(x)
     for k in want:
         np.testing.assert_array_equal(got[k], want[k])
 
